@@ -279,6 +279,8 @@ def main():
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for name, fn in BENCHES.items():
         if args.only and name != args.only:
             continue

@@ -5,9 +5,9 @@ The device engine (`repro.core.mis_device.DeviceSBTS`) runs the SBTS
 local search as ONE vmapped Pallas kernel step over K independent
 trajectories in lock step — counter-based RNG (`jax.random.fold_in`
 streams keyed on (seed, trajectory, iteration)), so runs are
-bit-reproducible and resume-safe.  On CPU the kernel executes in
-interpret mode (the CI-validated path); on a real accelerator the same
-program scales K with lane width.  `map_dfg` keeps the harvest loop
+bit-reproducible and resume-safe.  On a TPU the kernel runs compiled
+(`python chip_smoke.py` checks that path at full size); on the CPU it
+runs in Pallas interpret mode.  `map_dfg` keeps the harvest loop
 (dedupe -> repair -> validate) on the host — only the MIS search moves
 on-device — so golden (II, routing-PE) results are unchanged.
 
@@ -26,6 +26,9 @@ from repro.core.conflict import build_conflict_graph      # noqa: E402
 from repro.core.mis_device import (DeviceSBTS,            # noqa: E402
                                    differential_vs_numpy)
 from repro.core.schedule import schedule_dfg              # noqa: E402
+from repro.compile_cache import enable_compile_cache      # noqa: E402
+
+enable_compile_cache()
 
 cgra = CGRAConfig()
 dfg = make_cnkm(2, 6)

@@ -370,6 +370,8 @@ def _map_dfg_portfolio(dfg: DFG, cgra: CGRAConfig, opts: "MapOptions",
                         else 0
                     psp.set(iters=sbts.it - start_it, best=best_cov,
                             coverage=best_cov / n_ops if n_ops else 1.0)
+                    if device_engine:
+                        psp.set(interpret=sbts.interpret)
                     trc.gauge("portfolio.best", best_cov)
                     trc.gauge("portfolio.coverage",
                               best_cov / n_ops if n_ops else 1.0)

@@ -176,8 +176,10 @@ def build_conflict_graph(sched: ScheduledDFG, cgra: CGRAConfig,
     conflict-matrix kernel, "packed" = the packed-word variant's host
     oracle (dense ref + pack), "packed-pallas" = the packed-word Pallas
     kernel whose uint64 rows feed `BitsetGraph` directly — the TPU
-    offload path with no python pack step (requires a TPU backend; the
-    interpret-mode equivalence lives in tests/test_kernels.py).
+    offload path with no python pack step.  It runs compiled on a TPU
+    and in Pallas interpret mode on the CPU backend
+    (`repro.kernels.interpret_mode`); the span records which as its
+    ``interpret`` attr.
 
     ``tracer`` (default None) records the build as a "conflict-build"
     span; the edge popcount for the span attrs is only paid on a live
@@ -188,6 +190,9 @@ def build_conflict_graph(sched: ScheduledDFG, cgra: CGRAConfig,
         if tracer is not None:
             sp.set(n_vertices=cg.n,
                    n_edges=int(np.bitwise_count(cg.bits.rows).sum()) // 2)
+            if use_kernel == "packed-pallas":
+                from repro.kernels import interpret_mode
+                sp.set(interpret=interpret_mode())
         return cg
 
 

@@ -11,8 +11,10 @@ advances every trajectory:
 - **Conflict-count evaluation** runs on packed uint32 adjacency rows
   (`BitsetGraph.rows_u32`) through the `kernels.sbts_step` Pallas
   kernel: one AND+popcount contraction yields |N(v) ∩ S_k| for every
-  (trajectory, vertex) pair.  Interpret mode (CPU CI) traces the same
-  kernel through XLA, so the compiled path is exercised end to end.
+  (trajectory, vertex) pair.  On a TPU the kernel runs compiled; on
+  the CPU backend (the test suite) it runs in Pallas interpret mode.
+  One jitted chunk program is built per engine shape and shared by
+  every engine of that shape (`_build_chunk`).
 - **The per-seed step** (`_seed_step` below) is a pure jittable
   function of one trajectory's slice — tabu-guarded add/swap selection
   and plateau perturbation — ``vmap``ped over the K seeds; steps are
@@ -68,20 +70,23 @@ def _pad_n(n: int) -> int:
     return max(_LANE, -(-n // _LANE) * _LANE)
 
 
-def _build_chunk(n: int, n_pad: int, k: int, tenure: int, seed: int,
-                 block_n: int, block_k: int, interpret: bool):
-    """Compile-time closure: returns the jitted chunk advancer
-    ``(rows32, state, it0, n_steps) -> state`` with ``n_steps``
-    static.  ``state`` is the tuple (in_s, tabu, stall, thresh, best,
-    best_size) of device arrays."""
+@functools.lru_cache(maxsize=32)
+def _build_chunk(n_pad: int, k: int, tenure: int, block_n: int,
+                 block_k: int, interpret: bool):
+    """The jitted chunk advancer for one engine shape,
+    ``(rows32, state, base_key, n, it0, n_steps) -> state``.  ``state``
+    is the tuple (in_s, tabu, stall, thresh, best, best_size) of device
+    arrays; the RNG key, the live vertex count ``n`` and the step count
+    are traced arguments, so every `DeviceSBTS` of one
+    (n_pad, K, tenure, block sizes, mode) shares one compiled program
+    whatever its seed — `map_dfg` builds a fresh engine, with a fresh
+    seed, for every (II, jitter) attempt."""
     import jax
     import jax.numpy as jnp
 
     from repro.kernels.sbts_step.kernel import selection_counts_pallas
 
     w = n_pad // 32
-    base_key = jax.random.PRNGKey(seed)
-    valid = jnp.arange(n_pad) < n
     bit_w = jnp.left_shift(jnp.uint32(1), jnp.arange(32, dtype=jnp.uint32))
 
     def pack(bits):
@@ -99,7 +104,7 @@ def _build_chunk(n: int, n_pad: int, k: int, tenure: int, seed: int,
         return ((words[:, None] >> jnp.arange(32, dtype=jnp.uint32))
                 & jnp.uint32(1)).astype(bool).reshape(n_pad)
 
-    def draws(it):
+    def draws(base_key, it):
         """Counter-based per-(seed, iteration) randomness."""
         def one(sid):
             kit = jax.random.fold_in(
@@ -112,7 +117,7 @@ def _build_chunk(n: int, n_pad: int, k: int, tenure: int, seed: int,
             return r1, r2, j4, dth
         return jax.vmap(one)(jnp.arange(k))
 
-    def _seed_step(rows32, it, in_s, tabu, stall, thresh, best,
+    def _seed_step(rows32, valid, it, in_s, tabu, stall, thresh, best,
                    best_size, conf, aconf, samp, sconf, r1, r2, j4,
                    dth):
         """One trajectory's add/swap/perturb update (vmapped over K)."""
@@ -164,25 +169,27 @@ def _build_chunk(n: int, n_pad: int, k: int, tenure: int, seed: int,
 
     vstep = jax.vmap(
         _seed_step,
-        in_axes=(None, None) + (0,) * 14)
+        in_axes=(None, None, None) + (0,) * 14)
 
-    def lockstep(rows32, state, it):
+    def lockstep(rows32, valid, base_key, state, it):
         in_s, tabu, stall, thresh, best, best_size = state
-        r1, r2, j4, dth = draws(it)
+        r1, r2, j4, dth = draws(base_key, it)
         conf = counts(rows32, in_s)
         addable = valid[None] & ~in_s & (conf == 0) & (tabu <= it)
         aconf = counts(rows32, addable)
         samp = addable & (aconf > 0) \
             & (r1 < 1.0 / (1.0 + aconf.astype(jnp.float32)))
         sconf = counts(rows32, samp)
-        return vstep(rows32, it, in_s, tabu, stall, thresh, best,
+        return vstep(rows32, valid, it, in_s, tabu, stall, thresh, best,
                      best_size, conf, aconf, samp, sconf, r1, r2, j4,
                      dth)
 
-    @functools.partial(jax.jit, static_argnames=("n_steps",))
-    def chunk(rows32, state, it0, n_steps: int):
+    @jax.jit
+    def chunk(rows32, state, base_key, n, it0, n_steps):
+        valid = jnp.arange(n_pad) < n
+
         def body(i, st):
-            return lockstep(rows32, st, it0 + i)
+            return lockstep(rows32, valid, base_key, st, it0 + i)
         return jax.lax.fori_loop(0, n_steps, body, state)
 
     return chunk
@@ -191,9 +198,11 @@ def _build_chunk(n: int, n_pad: int, k: int, tenure: int, seed: int,
 class DeviceSBTS:
     """Device-resident drop-in for the `PortfolioSBTS` harvest-loop
     surface: ``run`` / ``best`` / ``best_size`` / ``it`` / ``rearm`` /
-    ``reset_seed``.  ``interpret=None`` auto-selects interpret mode on
-    CPU backends (the CI-validated path) and compiled Pallas
-    elsewhere.  ``inits`` entries must be independent sets (e.g.
+    ``reset_seed``.  ``interpret=None`` picks the mode from the backend
+    (`repro.kernels.interpret_mode`: interpret on CPU, compiled Pallas
+    on an accelerator); ``self.interpret`` records the mode that runs,
+    and `map_dfg` copies it onto its "portfolio-device" spans.
+    ``inits`` entries must be independent sets (e.g.
     `conflict.constructive_init` results); ``None`` entries and the
     seeds beyond ``len(inits)`` start cold — the add phase doubles as
     a randomized greedy construction, so cold seeds are cheap."""
@@ -203,8 +212,9 @@ class DeviceSBTS:
                  interpret: bool | None = None, chunk: int = 64,
                  block_n: int = 1024, block_k: int = 8):
         if interpret is None:
-            import jax
-            interpret = jax.default_backend() == "cpu"
+            from repro.kernels import interpret_mode
+            interpret = interpret_mode()
+        self.interpret = bool(interpret)
         self.g = g
         n = g.n
         self.k = int(max(k, len(inits) if inits else 0))
@@ -223,11 +233,13 @@ class DeviceSBTS:
         self._best = self.in_s.copy()
         self.best_size = self._best.sum(axis=1).astype(np.int32)
         if n and self.k:
+            import jax
             import jax.numpy as jnp
             self._rows32 = jnp.asarray(g.rows_u32(self._n_pad))
+            self._key = jax.random.PRNGKey(self.seed)
             self._chunk = _build_chunk(
-                n, self._n_pad, self.k, self.tenure, self.seed,
-                block_n, block_k, interpret)
+                self._n_pad, self.k, self.tenure, int(block_n),
+                int(block_k), self.interpret)
         else:
             self._rows32 = None
             self._chunk = None
@@ -265,7 +277,8 @@ class DeviceSBTS:
             if cancel is not None and cancel.is_set():
                 break
             n_steps = min(self.chunk_size, max_iters - done)
-            state = self._chunk(self._rows32, state, self.it, n_steps)
+            state = self._chunk(self._rows32, state, self._key,
+                                self.g.n, self.it, n_steps)
             self.it += n_steps
             done += n_steps
             iters_counter.inc(n_steps)

@@ -174,11 +174,18 @@ def race_map_dfg(dfg: DFG, cgra: CGRAConfig,
     finally:
         pool.shutdown(wait=True)
     with rsp:
+        # A crashed side is not hidden behind the survivor's answer: the
+        # race span and the race-winner event name every side error.
+        side_errors = {side: f"{type(exc).__name__}: {exc}"[:200]
+                       for side, exc in sorted(errors.items())}
+        if side_errors:
+            rsp.set(side_errors=side_errors)
         if winner is not None:
             side, res = winner
             rsp.set(winner=side)
             rec.emit("race-winner", winner=side,
-                     cancel_latency_s=cancel_latency)
+                     cancel_latency_s=cancel_latency,
+                     side_errors=side_errors)
             res = dataclasses.replace(res, backend=f"race:{side}")
             if record is not None:
                 # A sound negative (proved infeasible) is still a
@@ -193,7 +200,8 @@ def race_map_dfg(dfg: DFG, cgra: CGRAConfig,
         # prover's.
         rsp.set(winner="none")
         rec.emit("race-winner", winner="none",
-                 cancel_latency_s=cancel_latency)
+                 cancel_latency_s=cancel_latency,
+                 side_errors=side_errors)
         for side in ("portfolio", "exact"):
             if side in held:
                 res = dataclasses.replace(held[side],

@@ -25,7 +25,7 @@ def conflict_matrix(vertices, *, use_pallas: bool = False,
 
 
 def conflict_matrix_packed(vertices, *, use_pallas: bool = False,
-                           interpret: bool = False) -> np.ndarray:
+                           interpret: bool | None = None) -> np.ndarray:
     """core.conflict.Vertex list -> packed ``uint64 [n, ceil(n/64)]``
     adjacency rows, the layout `core.bitset.BitsetGraph` consumes.
 
@@ -33,7 +33,8 @@ def conflict_matrix_packed(vertices, *, use_pallas: bool = False,
     reinterpreted pairwise as uint64 on the host (little-endian bit
     order end to end), so the accelerator path feeds the bitset engine
     with no python pack step; the host path packs the dense-bool
-    reference — which stays the oracle either way."""
+    reference — which stays the oracle either way.  ``interpret=None``
+    picks the Pallas mode from the backend (`repro.kernels.interpret_mode`)."""
     from repro.core.bitset import n_words, pack_bool_rows
 
     feat = ref.encode(vertices)
@@ -41,6 +42,9 @@ def conflict_matrix_packed(vertices, *, use_pallas: bool = False,
     if not use_pallas:
         return pack_bool_rows(ref.conflict_matrix_ref(feat))
     from . import kernel
+    if interpret is None:
+        from repro.kernels import interpret_mode
+        interpret = interpret_mode()
     w32 = np.asarray(kernel.conflict_matrix_packed_pallas(
         feat, interpret=interpret))
     w32 = np.ascontiguousarray(w32)

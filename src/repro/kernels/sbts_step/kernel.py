@@ -8,17 +8,22 @@ set, the Luby sample).  With the adjacency as packed uint32 words
 ``rows32 [n_pad, W]`` (`BitsetGraph.rows_u32`) and the selections as
 ``sel32 [K, W]``, that is one AND + ``lax.population_count`` + word
 reduction per (k, v) pair — the all-pairs popcount this kernel tiles
-over a (seed-block, vertex-block) grid.
+over a (vertex-block, seed-block) grid.
 
-Tiling: ``block_k × block_n × W`` words are materialised per grid
-cell, so the defaults (8 × 1024) keep the working set a few MiB even
-at the 16x16-fabric |V_C| ~ 10^4 scale.  Block sizes that do not
-divide the operand shapes fall back to a single block on that axis —
-callers pad ``n_pad`` to a multiple of 128 (`mis_device._pad_n`), so
-the fallback only triggers for small K.  Interpret mode is the
-CI-validated path (this repo's runners are CPU-only); real-TPU
-lane-width tuning of ``W`` (last-dim 128 alignment) is the standing
-ROADMAP gap shared with `kernels.conflict_matrix`.
+Layout: the wrapper hands the kernel the adjacency transposed,
+``[W, n_pad]``, so vertices run along the 128 lanes and the word
+reduction runs down the sublanes — plain vector adds, no cross-lane
+reduce.  Each grid cell holds one ``(W, block_n)`` adjacency tile
+(fetched once per vertex block: the seed axis is the inner grid axis)
+and one ``(block_k, W)`` selection tile, and walks the ``block_k``
+seeds one at a time, so the live intermediate is a single
+``(W, block_n)`` word tile: ~0.3 MiB at the 16x16 fabric's
+n_pad 10496, well inside the TPU's scoped VMEM.  ``block_n`` is the
+largest multiple of 128 that divides ``n_pad`` and does not exceed the
+requested cap (callers pad ``n_pad`` to a multiple of 128,
+`mis_device._pad_n`); a ``K`` that ``block_k`` does not divide runs as
+one seed block.  `tests/test_tpu_compile.py` compiles this kernel for a
+TPU v5e at the 8x8 and 16x16 fabric sizes.
 """
 
 from __future__ import annotations
@@ -29,12 +34,28 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_LANES = 128
 
-def _counts_kernel(rows_ref, sel_ref, out_ref):
-    rows = rows_ref[...]                      # (block_n, W) uint32
-    sel = sel_ref[...]                        # (block_k, W) uint32
-    hits = jax.lax.population_count(rows[None, :, :] & sel[:, None, :])
-    out_ref[...] = hits.astype(jnp.int32).sum(axis=-1)
+
+def _vertex_block(n_pad: int, cap: int) -> int:
+    """Largest multiple of 128 that divides ``n_pad`` and is <= ``cap``
+    (``n_pad`` itself when it is not a multiple of 128)."""
+    if n_pad % _LANES:
+        return n_pad
+    b = max(_LANES, min(cap, n_pad) // _LANES * _LANES)
+    while n_pad % b:
+        b -= _LANES
+    return b
+
+
+def _counts_kernel(rows_t_ref, sel_ref, out_ref):
+    rows_t = rows_t_ref[...]                  # (W, block_n) uint32
+    sel_t = sel_ref[...].T                    # (W, block_k) uint32
+    counts = []
+    for kk in range(sel_ref.shape[0]):
+        hits = jax.lax.population_count(rows_t & sel_t[:, kk:kk + 1])
+        counts.append(hits.astype(jnp.int32).sum(axis=0, keepdims=True))
+    out_ref[...] = jnp.concatenate(counts, axis=0)
 
 
 @functools.partial(jax.jit,
@@ -43,22 +64,22 @@ def selection_counts_pallas(rows32, sel32, *, block_n: int = 1024,
                             block_k: int = 8,
                             interpret: bool = False):
     """``int32 [K, n_pad]`` of ``popcount(rows32[v] & sel32[k])`` over
-    the word axis — |N(v) ∩ S_k| for every (trajectory, vertex) pair."""
+    the word axis — |N(v) ∩ S_k| for every (trajectory, vertex) pair.
+    ``block_n`` caps the vertex block (see `_vertex_block`)."""
     n_pad, w = rows32.shape
     k, w2 = sel32.shape
     assert w == w2, (rows32.shape, sel32.shape)
-    if n_pad % block_n:
-        block_n = n_pad
+    block_n = _vertex_block(n_pad, block_n)
     if k % block_k:
         block_k = k
-    grid = (k // block_k, n_pad // block_n)
+    grid = (n_pad // block_n, k // block_k)
     return pl.pallas_call(
         _counts_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((block_n, w), lambda kk, i: (i, 0)),
-                  pl.BlockSpec((block_k, w), lambda kk, i: (kk, 0))],
+        in_specs=[pl.BlockSpec((w, block_n), lambda i, kk: (0, i)),
+                  pl.BlockSpec((block_k, w), lambda i, kk: (kk, 0))],
         out_specs=pl.BlockSpec((block_k, block_n),
-                               lambda kk, i: (kk, i)),
+                               lambda i, kk: (kk, i)),
         out_shape=jax.ShapeDtypeStruct((k, n_pad), jnp.int32),
         interpret=interpret,
-    )(rows32, sel32)
+    )(rows32.T, sel32)

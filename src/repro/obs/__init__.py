@@ -40,7 +40,8 @@ span name        emitted by / attributes
 ``static-prepass``  demand-bound II floor pass — ``floor``, ``skipped``
 ``schedule``     per-(II, jitter) modulo schedule — ``ii``, ``jitter``
 ``conflict-build``  `conflict.build_conflict_graph` — ``n_vertices``,
-                 ``n_edges``
+                 ``n_edges``; ``interpret`` (Pallas mode) when the
+                 packed Pallas kernel built the graph
 ``certify``      `certify.certify_ii_infeasible` — ``ii``, ``jitter``,
                  ``stage``, ``nodes``, ``orbit_skips``
 ``portfolio-init``  constructive warm-starts + `PortfolioSBTS` build
@@ -50,14 +51,16 @@ span name        emitted by / attributes
                  ``coverage``, ``best``
 ``portfolio-device``  one `mis_device.DeviceSBTS` harvest round (the
                  accelerator-resident engine, ``engine="device"``) —
-                 same attrs as ``portfolio``
+                 same attrs as ``portfolio``, plus ``interpret`` (True
+                 when the Pallas kernel ran in interpret mode)
 ``repair``       ejection-chain repair of a near-complete solution
                  (includes the lazy row-cache unpack) — ``shortfall``
 ``validate``     `validate_mapping` replay of a candidate solution
 ``exact-csp``    `exact.backend` per-(II, jitter) complete search —
                  ``ii``, ``jitter``, ``verdict``, ``nodes``
 ``race``         `exact.race` arbitration — ``winner``,
-                 ``cancel_latency_s``, ``loser_iters_after_cancel``
+                 ``cancel_latency_s``, ``loser_iters_after_cancel``,
+                 ``side_errors`` (only when a side raised)
 ``race-side``    one side of the race — ``side``, ``wall_s``
 ``comap-region``  `comap.co_map` per-region mapping — ``region``,
                  ``round``, ``ii``
@@ -98,7 +101,8 @@ event kind       emitted by / attributes
 ``cancelled``    cooperative cancel observed — ``ii``
 ``race-cancel``  `exact.race` cancel request issued — ``winner``
 ``race-winner``  race arbitration settled — ``winner``,
-                 ``cancel_latency_s``
+                 ``cancel_latency_s``, ``side_errors`` (side -> error
+                 of a side that raised; empty when none did)
 ``comap-round``  one co-mapping round finished — ``ii``, ``round``,
                  ``ok_regions``
 ``comap-arbitrate``  arbitration verdict — ``ii``, ``round``, ``ok``

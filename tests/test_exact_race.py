@@ -164,9 +164,16 @@ def test_crashed_prover_degrades_to_portfolio(monkeypatch):
         raise RuntimeError("prover died")
 
     monkeypatch.setattr(race_mod, "exact_map_dfg", boom)
-    r = map_dfg(make_cnkm(2, 6), CGRA, mode="busmap", backend="race")
+    from repro.obs.trace import Tracer
+    tracer = Tracer()
+    r = map_dfg(make_cnkm(2, 6), CGRA, mode="busmap", backend="race",
+                tracer=tracer)
     assert r.ok
     assert r.backend == "race:portfolio"
+    # The survivor wins, but the crash is on record, not swallowed.
+    race = [s for s in tracer.finished if s.name == "race"]
+    assert race[-1].attrs["side_errors"] == {
+        "exact": "RuntimeError: prover died"}
 
 
 def test_crashed_portfolio_degrades_to_prover(monkeypatch):

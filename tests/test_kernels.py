@@ -172,6 +172,32 @@ def test_conflict_matrix_packed_matches_bitset_rows():
         assert (conflict_matrix_packed(cg.vertices) == ref_rows).all()
 
 
+
+def test_conflict_matrix_packed_default_blocks_span_column_blocks():
+    """Default blocks (the ones the TPU compiles): 4200 columns need two
+    4096-column word blocks plus a ragged row block, and every padded
+    column must stay zero once the words are viewed as uint64 rows."""
+    import numpy as onp
+
+    from repro.core.bitset import n_words, pack_bool_rows
+    from repro.kernels.conflict_matrix.kernel import \
+        conflict_matrix_packed_pallas
+    from repro.kernels.conflict_matrix.ref import conflict_matrix_ref
+    rng = onp.random.default_rng(7)
+    n = 4200
+    feat = onp.stack([rng.integers(0, 3, n), rng.integers(0, 300, n),
+                      rng.integers(0, 4, n), rng.integers(0, 8, n),
+                      rng.integers(0, 8, n), rng.integers(0, 8, n),
+                      rng.integers(-1, 2, n), rng.integers(0, 3, n)],
+                     axis=1).astype(onp.int32)
+    w32 = onp.ascontiguousarray(onp.asarray(
+        conflict_matrix_packed_pallas(jnp.asarray(feat), interpret=True)))
+    assert w32.shape == (n, 2 * 4096 // 32)
+    rows = w32.view(onp.uint64)
+    ref = pack_bool_rows(conflict_matrix_ref(feat))
+    assert (rows[:, :n_words(n)] == ref).all()
+    assert not rows[:, n_words(n):].any()
+
 def test_conflict_matrix_packed_feeds_bitset_graph():
     from repro.core import make_cnkm, schedule_dfg
     from repro.core.cgra import CGRAConfig
@@ -181,3 +207,21 @@ def test_conflict_matrix_packed_feeds_bitset_graph():
     packed = build_conflict_graph(sched, CGRAConfig(), use_kernel="packed")
     assert (packed.bits.rows == ref.bits.rows).all()
     assert packed.n_edges == ref.n_edges
+
+
+# ---------------------------------------------------- sbts_step counts
+@pytest.mark.parametrize("n_pad,k,block_n", [(128, 4, 1024), (384, 16, 256),
+                                             (640, 24, 1024)])
+def test_selection_counts_pallas_matches_ref(n_pad, k, block_n):
+    """The device engine's popcount kernel equals the numpy oracle bit
+    for bit, across vertex blocks that divide n_pad (384 -> 128 under a
+    256 cap, 640 -> 640) and seed counts block_k does not divide."""
+    from repro.kernels.sbts_step.kernel import selection_counts_pallas
+    from repro.kernels.sbts_step.ref import selection_counts_ref
+    rng = np.random.default_rng(n_pad + k)
+    rows = rng.integers(0, 1 << 32, (n_pad, n_pad // 32), dtype=np.uint32)
+    sel = rng.integers(0, 1 << 32, (k, n_pad // 32), dtype=np.uint32)
+    out = selection_counts_pallas(rows, sel, block_n=block_n,
+                                  interpret=True)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  selection_counts_ref(rows, sel))
