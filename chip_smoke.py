@@ -219,8 +219,9 @@ def phase_maps(cases, *, device_seeds: int = 1024,
                   flush=True)
             if engine == "device":
                 device_iters += tracer.counter_value("portfolio.iters")
-                print("[tpu]     phases: " + ", ".join(
-                    f"{ph} {v['total_s']:.3f} s x{v['count']}"
+                print("[tpu]     phases (self s / total s): " + ", ".join(
+                    f"{ph} {v['self_s']:.3f}/{v['total_s']:.3f} "
+                    f"x{v['count']}"
                     for ph, v in tracer.phase_breakdown().items()),
                     flush=True)
             if not res.ok:

@@ -1,14 +1,15 @@
 """Tracing demo: map two kernels with a live `repro.obs.Tracer`, write
 Perfetto-openable Chrome trace JSON under ``artifacts/trace/``, and
-print the per-phase wall breakdown.
+print the per-phase wall breakdown (self time, the share of map-dfg's
+wall, and the whole durations).
 
 Two workloads, deliberately different phase profiles:
 
 - **C5K5** (paper kernel, 4x4 fabric): certificate stages + the exact
   CSP fast path dominate — the portfolio barely runs.
 - **tight 16x16** (`make_tightly_coupled` on a 16x16 PEA, group-move
-  kick on): the portfolio harvest rounds dominate, and the coverage
-  gauge shows the kick breaking the stall.
+  kick on): the portfolio harvest rounds dominate, and the rounds'
+  ``coverage`` attribute shows the kick breaking the stall.
 
 A third leg demos the rest of the observability surface: a
 flight-recorded failure rendered as an explain report
@@ -40,11 +41,13 @@ def _print_breakdown(name: str, tracer: Tracer) -> None:
     total = sum(a["total_s"] for n, a in bd.items() if n == "map-dfg")
     print(f"\n{name}: phase breakdown "
           f"({len(tracer.finished)} spans, map-dfg {total * 1e3:.1f} ms)")
-    print(f"  {'phase':<16} {'count':>6} {'total ms':>10} {'share':>7}")
+    print(f"  {'phase':<16} {'count':>6} {'self ms':>10} {'share':>7} "
+          f"{'total ms':>10}")
     for phase, agg in bd.items():
-        share = agg["total_s"] / total if total else 0.0
+        share = agg["self_s"] / total if total else 0.0
         print(f"  {phase:<16} {agg['count']:>6} "
-              f"{agg['total_s'] * 1e3:>10.2f} {share:>6.1%}")
+              f"{agg['self_s'] * 1e3:>10.2f} {share:>6.1%} "
+              f"{agg['total_s'] * 1e3:>10.2f}")
     counters = tracer.registry.snapshot()["counters"]
     if counters:
         print("  counters: " + ", ".join(

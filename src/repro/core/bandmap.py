@@ -301,11 +301,13 @@ def _map_dfg_portfolio(dfg: DFG, cgra: CGRAConfig, opts: "MapOptions",
                                  for i in mis_indices(csp_sol)}
                     with trc.span("validate", ii=cur_ii, source="csp"):
                         report = validate_mapping(sched, cgra, placement)
+                        trc.count("validate.calls")
+                        if not report.ok:
+                            trc.count("validate.rejects")
+                            rec.emit("validate-reject", ii=cur_ii,
+                                     source="csp")
                     last = (sched, placement, report, n_ops,
                             (cg.n, cg.n_edges))
-                    if not report.ok:
-                        rec.emit("validate-reject", ii=cur_ii,
-                                 source="csp")
                     if report.ok:
                         return MappingResult(
                             ok=True, mode=mode, ii=cur_ii, mii=the_mii,
@@ -372,9 +374,6 @@ def _map_dfg_portfolio(dfg: DFG, cgra: CGRAConfig, opts: "MapOptions",
                             coverage=best_cov / n_ops if n_ops else 1.0)
                     if device_engine:
                         psp.set(interpret=sbts.interpret)
-                    trc.gauge("portfolio.best", best_cov)
-                    trc.gauge("portfolio.coverage",
-                              best_cov / n_ops if n_ops else 1.0)
                 rec.emit("harvest-round", ii=cur_ii, jitter=jitter,
                          round=rnd, best=best_cov,
                          coverage=best_cov / n_ops if n_ops else 1.0)
@@ -405,7 +404,9 @@ def _map_dfg_portfolio(dfg: DFG, cgra: CGRAConfig, opts: "MapOptions",
                                     cg.bits, sol, cg.op_vertices, op_of,
                                     depth=4, seed=rs * 13 + rk,
                                     row_cache=row_cache)
+                                trc.count("repair.tries")
                                 if int(fixed.sum()) >= n_ops:
+                                    trc.count("repair.fixed")
                                     sol = fixed
                                     break
                             else:
@@ -420,11 +421,13 @@ def _map_dfg_portfolio(dfg: DFG, cgra: CGRAConfig, opts: "MapOptions",
                     with trc.span("validate", ii=cur_ii,
                                   source="portfolio"):
                         report = validate_mapping(sched, cgra, placement)
+                        trc.count("validate.calls")
+                        if not report.ok:
+                            trc.count("validate.rejects")
+                            rec.emit("validate-reject", ii=cur_ii,
+                                     source="portfolio")
                     last = (sched, placement, report, size,
                             (cg.n, cg.n_edges))
-                    if not report.ok:
-                        rec.emit("validate-reject", ii=cur_ii,
-                                 source="portfolio")
                     if report.ok:
                         return MappingResult(
                             ok=True, mode=mode, ii=cur_ii, mii=the_mii,

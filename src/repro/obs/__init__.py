@@ -31,7 +31,9 @@ Span taxonomy (STABLE PUBLIC VOCABULARY)
 These phase names are an interface: `benchmarks/bench_mis.py` records
 per-row phase breakdowns keyed on them and `check_regression.py` gates
 counters derived from traced runs, so renaming one is a breaking change
-to the bench baseline.  The engine emits:
+to the bench baseline.  Each span is also a `jax.profiler`
+annotation of the same name, so a profiled run shows it on the host
+plane beside the device operations.  The engine emits:
 
 ===============  =====================================================
 span name        emitted by / attributes
@@ -68,12 +70,30 @@ span name        emitted by / attributes
 ``merge-replay``  merged-binding validation in `co_map`
 ===============  =====================================================
 
-Counters (deterministic, gated by ``check_regression.py``):
+Counters (deterministic; ``check_regression.py`` gates
+``certify.csp_nodes`` and ``portfolio.iters``):
 ``portfolio.iters``, ``portfolio.kicks``, ``certify.csp_nodes``,
 ``certify.orbit_skips``, ``exact.validations``,
-``comap.arbitration_retries``.
-Gauges: ``portfolio.coverage``, ``portfolio.best``, serve's
-``queue_depth``.
+``comap.arbitration_retries``, and four counted in `map_dfg`'s harvest
+loop:
+
+=====================  ================================================
+counter                one per / counted inside
+=====================  ================================================
+``repair.tries``       `mis.ejection_repair` call / ``repair``
+``repair.fixed``       such call whose result covers every op /
+                       ``repair``
+``validate.calls``     `validate_mapping` of a complete candidate, both
+                       sources (``csp``, ``portfolio``) / ``validate``
+``validate.rejects``   candidate the validator rejected / ``validate``
+=====================  ================================================
+
+Counts sit on spans: an increment made through a live `Tracer` also
+lands in the ``counts`` of the innermost open span of the calling
+thread (`SpanRecord.counts`, self counts like self times), so a
+request's spans sum to its totals and a reader of spans needs no
+registry.  The registry keeps the same totals.
+Gauges: serve's ``queue_depth``.
 
 Flight-event taxonomy (STABLE PUBLIC VOCABULARY)
 ------------------------------------------------

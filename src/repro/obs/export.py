@@ -29,7 +29,8 @@ def to_json(tracer: Tracer) -> dict:
     return {
         "spans": [
             dict(sid=r.sid, parent=r.parent, name=r.name, t0=r.t0,
-                 t1=r.t1, tid=r.tid, depth=r.depth, attrs=r.attrs)
+                 t1=r.t1, tid=r.tid, depth=r.depth, attrs=r.attrs,
+                 counts=r.counts)
             for r in tracer.finished
         ],
         "metrics": tracer.registry.snapshot(),
@@ -43,14 +44,19 @@ def from_json(payload: dict) -> list[SpanRecord]:
 
 def to_chrome_trace(tracer: Tracer, process_name: str = "repro") -> dict:
     """Chrome trace-event JSON object format.  Complete ("X") events,
-    microsecond timestamps; counters become one final "C" event so the
-    totals show as a Perfetto counter track."""
+    microsecond timestamps, a span's own counts under its ``counts``
+    argument; counter totals become one final "C" event so they show as
+    a Perfetto counter track."""
     events = []
     tids = {}
     for rec in tracer.finished:
         # Perfetto wants small stable tids; remap OS idents in order of
         # first appearance so track 0 is the main thread.
         tid = tids.setdefault(rec.tid, len(tids))
+        args = {k: _jsonable(v) for k, v in rec.attrs.items()}
+        if rec.counts:
+            args["counts"] = {k: _jsonable(v)
+                              for k, v in rec.counts.items()}
         events.append({
             "name": rec.name,
             "ph": "X",
@@ -58,7 +64,7 @@ def to_chrome_trace(tracer: Tracer, process_name: str = "repro") -> dict:
             "dur": (rec.t1 - rec.t0) * 1e6,
             "pid": 0,
             "tid": tid,
-            "args": {k: _jsonable(v) for k, v in rec.attrs.items()},
+            "args": args,
         })
     counters = tracer.registry.snapshot()["counters"]
     if counters:

@@ -7,10 +7,10 @@ engine and the serving tier emit:
   portfolio iterations, CSP nodes expanded).  Increments are
   lock-guarded, so concurrent writers (serve worker threads, the two
   sides of a mapping race) never lose counts.
-- **gauges** — point-in-time samples (queue depth at batch admission,
-  per-seed portfolio coverage).  The registry keeps last/min/max plus
-  the running count/sum, so a snapshot can report the latest value and
-  the envelope without retaining every sample.
+- **gauges** — point-in-time samples (queue depth at batch admission).
+  The registry keeps last/min/max plus the running count/sum, so a
+  snapshot can report the latest value and the envelope without
+  retaining every sample.
 - **histograms** — full sample lists summarised to p50/p95/p99 (via
   ``numpy.percentile``, linear interpolation) at snapshot time; the
   serving tier's request-latency percentiles live here.

@@ -9,6 +9,19 @@ its thread id and its parent span's id, which is exactly what the
 Chrome trace-event export (`obs.export`) needs to lay out per-thread
 timelines in Perfetto.
 
+Counts sit on spans: every counter increment made through a `Tracer`
+(`count`, or a handle from `counter`) is also added to the innermost
+open span of the calling thread, as that span's *self* count — an
+increment made while a child span is open belongs to the child.  The
+counts of a run's spans therefore sum to the increments made inside
+any span, just as self times add up to the root spans' wall.
+
+While a span is open the tracer also holds a
+`jax.profiler.TraceAnnotation` of the span's name, so under
+`jax.profiler` every span shows on the host plane of the same trace as
+the device operations, on the profiler's own clock.  jax is imported
+when a `Tracer` is built; `NullTracer` never touches the profiler.
+
 The tracer-threading rule (enforced by the ``tracer-default-none``
 AST-lint rule on the engine modules): every engine entry point accepts
 ``tracer=None``, converts it once via :func:`live` and never branches
@@ -24,7 +37,7 @@ Usage::
     with tracer.span("certify", ii=ii, jitter=j) as sp:
         ...
         sp.set(stage="exhausted", nodes=nodes)
-    tracer.count("certify.csp_nodes", nodes)
+        tracer.count("certify.csp_nodes", nodes)   # on the span too
 """
 
 from __future__ import annotations
@@ -48,6 +61,9 @@ class SpanRecord:
     tid: int            # OS thread ident of the recording thread
     depth: int          # nesting depth within its thread (0 = root)
     attrs: dict
+    # Counter increments made while this span was the innermost open
+    # span of its thread (self counts: a child's are not repeated here).
+    counts: dict = dataclasses.field(default_factory=dict)
 
     @property
     def dur_s(self) -> float:
@@ -58,7 +74,7 @@ class _LiveSpan:
     """Context-manager handle for an open span."""
 
     __slots__ = ("_tracer", "sid", "parent", "name", "t0", "depth",
-                 "attrs")
+                 "attrs", "counts", "annotation")
 
     def __init__(self, tracer: "Tracer", sid: int, parent: int,
                  name: str, depth: int, attrs: dict) -> None:
@@ -68,7 +84,9 @@ class _LiveSpan:
         self.name = name
         self.depth = depth
         self.attrs = attrs
+        self.counts: dict = {}
         self.t0 = _time.perf_counter()
+        self.annotation = tracer._annotation(name)
 
     def set(self, **attrs) -> "_LiveSpan":
         self.attrs.update(attrs)
@@ -105,6 +123,20 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _SpanCounter(Counter):
+    """`Counter` handle of a live `Tracer`: each increment also lands on
+    the innermost open span of the calling thread."""
+
+    __slots__ = ("_tracer",)
+
+    def __init__(self, tracer: "Tracer", name: str) -> None:
+        super().__init__(tracer.registry, name)
+        self._tracer = tracer
+
+    def inc(self, n: int | float = 1) -> None:
+        self._tracer.count(self.name, n)
+
+
 class Tracer:
     """See module docstring."""
 
@@ -120,6 +152,8 @@ class Tracer:
         self._finished: list[SpanRecord] = []
         self._next_sid = 0
         self._tls = threading.local()
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
 
     # ------------------------------------------------------------- spans
     def _stack(self) -> list:
@@ -140,6 +174,7 @@ class Tracer:
 
     def _finish(self, sp: _LiveSpan) -> None:
         t1 = _time.perf_counter()
+        sp.annotation.__exit__(None, None, None)
         stack = self._stack()
         # Tolerate out-of-order exits (a caller holding the handle past
         # an enclosing span): pop through to this span if present.
@@ -148,7 +183,7 @@ class Tracer:
         rec = SpanRecord(sid=sp.sid, parent=sp.parent, name=sp.name,
                          t0=sp.t0 - self.epoch, t1=t1 - self.epoch,
                          tid=threading.get_ident(), depth=sp.depth,
-                         attrs=dict(sp.attrs))
+                         attrs=dict(sp.attrs), counts=dict(sp.counts))
         with self._lock:
             self._finished.append(rec)
 
@@ -160,9 +195,14 @@ class Tracer:
     # ----------------------------------------------------------- metrics
     def count(self, name: str, n: int | float = 1) -> None:
         self.registry.inc(name, n)
+        stack = self._stack()
+        if stack:
+            own = stack[-1].counts
+            own[name] = own.get(name, 0) + n
 
     def counter(self, name: str) -> Counter:
-        return self.registry.counter(name)
+        self.registry.counter(name)
+        return _SpanCounter(self, name)
 
     def counter_value(self, name: str) -> int | float:
         return self.registry.counter_value(name)
@@ -173,14 +213,22 @@ class Tracer:
     # ----------------------------------------------------------- summary
     def phase_breakdown(self) -> dict[str, dict]:
         """Aggregate finished spans by name: ``{name: {"count": n,
-        "total_s": wall}}``, sorted by descending total.  Nested spans
-        each contribute their own full duration (attribution, not a
-        partition of wall time)."""
+        "total_s": wall, "self_s": own}}``, sorted by descending total.
+        ``total_s`` sums whole durations, so nested spans repeat their
+        children's time; ``self_s`` sums each span's duration less its
+        direct children's, a partition of the root spans' wall."""
+        recs = self.finished
+        child: dict[int, float] = {}
+        for rec in recs:
+            if rec.parent >= 0:
+                child[rec.parent] = child.get(rec.parent, 0.0) + rec.dur_s
         agg: dict[str, dict] = {}
-        for rec in self.finished:
-            slot = agg.setdefault(rec.name, {"count": 0, "total_s": 0.0})
+        for rec in recs:
+            slot = agg.setdefault(rec.name, {"count": 0, "total_s": 0.0,
+                                             "self_s": 0.0})
             slot["count"] += 1
             slot["total_s"] += rec.dur_s
+            slot["self_s"] += rec.dur_s - child.get(rec.sid, 0.0)
         return dict(sorted(agg.items(),
                            key=lambda kv: -kv[1]["total_s"]))
 

@@ -9,16 +9,20 @@ stragglers run under ``-m slow``, matching test_golden_results.
 """
 
 import json
+import os
 import threading
+import time
+import tracemalloc
 
 import numpy as np
 import pytest
 
 from repro.core import PAPER_KERNELS, cnkm_name, make_cnkm, map_dfg
 from repro.core.cgra import CGRAConfig
-from repro.obs import (NULL_TRACER, PHASES, MetricsRegistry, NullTracer,
-                       SpanRecord, Tracer, from_json, live,
+from repro.obs import (NULL_COUNTER, NULL_TRACER, PHASES, MetricsRegistry,
+                       NullTracer, SpanRecord, Tracer, from_json, live,
                        to_chrome_trace, to_json)
+from repro.obs import trace as trace_mod
 from repro.obs.trace import NULL_SPAN
 
 
@@ -103,6 +107,209 @@ def test_phase_breakdown_aggregates_and_sorts():
     assert totals == sorted(totals, reverse=True)
 
 
+def test_phase_breakdown_self_time_partitions_the_root():
+    """``self_s`` is the duration less the direct children's, the same
+    self time the benchmark's layer metrics read; summed over names it
+    is the root span's wall."""
+    tr = Tracer()
+    with tr.span("root"):
+        with tr.span("mid"):
+            with tr.span("leaf"):
+                time.sleep(0.002)
+            time.sleep(0.002)
+        with tr.span("leaf"):
+            time.sleep(0.002)
+    recs = {r.sid: r for r in tr.finished}
+    bd = tr.phase_breakdown()
+    for name in ("root", "mid", "leaf"):
+        own = sum(r.dur_s - sum(c.dur_s for c in recs.values()
+                                if c.parent == r.sid)
+                  for r in recs.values() if r.name == name)
+        assert bd[name]["self_s"] == pytest.approx(own, abs=1e-12)
+        assert 0 <= bd[name]["self_s"] <= bd[name]["total_s"]
+    assert sum(a["self_s"] for a in bd.values()) == \
+        pytest.approx(bd["root"]["total_s"], abs=1e-9)
+    assert bd["leaf"]["self_s"] == bd["leaf"]["total_s"]
+
+
+# ------------------------------------------------------ counts on spans
+
+def test_counts_land_on_the_innermost_open_span():
+    tr = Tracer()
+    tr.count("outside")                  # no span open: registry only
+    with tr.span("outer"):
+        tr.count("repair.tries")
+        handle = tr.counter("portfolio.iters")
+        handle.inc(3)
+        with tr.span("inner"):
+            tr.count("repair.tries", 2)
+            handle.inc(5)
+            tr.count("validate.calls")
+        tr.count("repair.tries")
+    recs = {r.name: r for r in tr.finished}
+    # Self counts: what was counted while the child was open is the
+    # child's, and the handle attributes exactly like `count`.
+    assert recs["outer"].counts == {"repair.tries": 2,
+                                    "portfolio.iters": 3}
+    assert recs["inner"].counts == {"repair.tries": 2,
+                                    "portfolio.iters": 5,
+                                    "validate.calls": 1}
+    # The registry totals are unchanged by the span bookkeeping.
+    assert tr.counter_value("repair.tries") == 4
+    assert tr.counter_value("portfolio.iters") == 8
+    assert tr.counter_value("outside") == 1
+    assert handle.value == 8
+    # A span with nothing counted carries an empty dict.
+    with tr.span("quiet"):
+        pass
+    assert tr.finished[-1].counts == {}
+
+
+def test_counts_of_two_threads_stay_apart():
+    tr = Tracer()
+    barrier = threading.Barrier(2)
+
+    def work(tag, n):
+        handle = tr.counter("portfolio.iters")
+        with tr.span("side", side=tag):
+            barrier.wait(timeout=30)     # both spans open at once
+            for _ in range(n):
+                tr.count("repair.tries")
+                handle.inc(2)
+            barrier.wait(timeout=30)
+
+    threads = [threading.Thread(target=work, args=(tag, n))
+               for tag, n in ((0, 300), (1, 700))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+        assert not t.is_alive()
+    sides = {r.attrs["side"]: r for r in tr.finished}
+    assert sides[0].counts == {"repair.tries": 300, "portfolio.iters": 600}
+    assert sides[1].counts == {"repair.tries": 700,
+                               "portfolio.iters": 1400}
+    assert tr.counter_value("repair.tries") == 1000
+
+
+def test_span_counts_sum_to_the_registry_totals():
+    """Every increment of a traced map is made inside its ``map-dfg``
+    span, so the spans' self counts add up to the registry's totals."""
+    tr = Tracer()
+    r = map_dfg(make_cnkm(5, 5), CGRAConfig(), tracer=tr)
+    assert r.ok
+    summed: dict = {}
+    for rec in tr.finished:
+        for k, v in rec.counts.items():
+            summed[k] = summed.get(k, 0) + v
+    totals = tr.registry.snapshot()["counters"]
+    assert {k: v for k, v in summed.items() if v} == \
+        {k: v for k, v in totals.items() if v}
+    assert summed["certify.csp_nodes"] > 0
+    assert summed["validate.calls"] - summed.get("validate.rejects", 0) \
+        == 1
+
+
+def test_null_tracer_counting_allocates_nothing():
+    """The untraced hot path: counts, counter handles and spans of the
+    `NullTracer` leave no allocation behind and take no lock."""
+    nt = live(None)
+    handle = nt.counter("portfolio.iters")
+
+    def hot(n):
+        for _ in range(n):
+            nt.count("repair.tries")
+            handle.inc(3)
+            with nt.span("repair"):
+                nt.count("validate.rejects")
+
+    hot(10)                               # warm any lazy interpreter state
+    tracemalloc.start()
+    try:
+        before = tracemalloc.take_snapshot()
+        hot(5000)
+        after = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    obs_dir = os.path.dirname(trace_mod.__file__)
+    grown = [d for d in after.compare_to(before, "filename")
+             if d.traceback[0].filename.startswith(obs_dir)
+             and d.size_diff > 0]
+    assert grown == []
+    assert handle is NULL_COUNTER
+    assert not hasattr(nt, "_lock") and not hasattr(nt, "_tls")
+
+
+def test_counts_round_trip_through_json_and_chrome():
+    tr = Tracer()
+    with tr.span("repair", shortfall=2):
+        tr.count("repair.tries", 6)
+        tr.count("repair.fixed")
+    with tr.span("validate"):
+        tr.count("validate.calls")
+    payload = json.loads(json.dumps(to_json(tr)))
+    spans = from_json(payload)
+    assert spans == tr.finished
+    assert [s.counts for s in spans] == [
+        {"repair.tries": 6, "repair.fixed": 1}, {"validate.calls": 1}]
+    # A payload written before spans carried counts still loads.
+    for sp in payload["spans"]:
+        del sp["counts"]
+    assert [s.counts for s in from_json(payload)] == [{}, {}]
+    doc = json.loads(json.dumps(to_chrome_trace(tr)))
+    xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert xs[0]["args"] == {"shortfall": 2, "counts": {
+        "repair.tries": 6, "repair.fixed": 1}}
+    assert xs[1]["args"] == {"counts": {"validate.calls": 1}}
+
+
+def test_repair_and_validate_counts_on_the_device_engine(monkeypatch):
+    """A traced device-engine map (interpret mode on the CPU) of a loop
+    kernel that repairs: every `ejection_repair` call is one
+    ``repair.tries`` on its ``repair`` span, every ``validate`` span one
+    ``validate.calls``, and exactly one validated candidate is kept."""
+    from repro.core import bandmap
+    from repro.core.workloads import make_loop_kernel
+    calls = []
+    real = bandmap.ejection_repair
+
+    def counted(*args, **kwargs):
+        out = real(*args, **kwargs)
+        calls.append(int(out.sum()))
+        return out
+
+    monkeypatch.setattr(bandmap, "ejection_repair", counted)
+    dfg = make_loop_kernel(2, 4, 3, 2, n_carries=128 % 3,
+                           max_distance=2, seed=128)
+    tr = Tracer()
+    res = map_dfg(dfg, CGRAConfig(), engine="device", tracer=tr)
+    assert res.ok
+    recs = tr.finished
+    counts = {n: tr.counter_value(n) for n in (
+        "repair.tries", "repair.fixed", "validate.calls",
+        "validate.rejects")}
+    assert calls and counts["repair.tries"] == len(calls)
+    assert counts["repair.fixed"] == sum(c >= res.n_ops for c in calls)
+    validates = [r for r in recs if r.name == "validate"]
+    assert counts["validate.calls"] == len(validates)
+    assert counts["validate.calls"] - counts["validate.rejects"] == 1
+    # Each count sits on the span of its work, as a self count.
+    where = {}
+    for r in recs:
+        for k, v in r.counts.items():
+            where.setdefault(k, set()).add(r.name)
+    assert where["repair.tries"] == where["repair.fixed"] == {"repair"}
+    assert where["validate.calls"] == where["validate.rejects"] == \
+        {"validate"}
+    assert sum(r.counts.get("repair.tries", 0) for r in recs) == \
+        len(calls)
+    assert any(r.name == "portfolio-device" for r in recs)
+    # No span opens inside the spans whose self time the benchmark reads.
+    sids = {r.sid: r.name for r in recs}
+    assert not [r.name for r in recs if sids.get(r.parent) in (
+        "repair", "certify", "conflict-build", "portfolio-device")]
+
+
 # --------------------------------------------------- NullTracer contract
 
 def test_null_tracer_is_allocation_free_singletons():
@@ -118,7 +325,7 @@ def test_null_tracer_is_allocation_free_singletons():
     c.inc(5)
     assert nt.counter_value("portfolio.iters") == 0
     nt.count("certify.csp_nodes", 41)
-    nt.gauge("portfolio.best", 3)
+    nt.gauge("queue_depth", 3)
     assert nt.phase_breakdown() == {}
     assert NullTracer().finished == ()
     with nt.span("ctx") as sp:
@@ -224,8 +431,8 @@ def test_histogram_percentiles_match_numpy():
 def test_gauge_tracks_last_min_max_mean():
     reg = MetricsRegistry()
     for v in (3, 1, 4, 1, 5):
-        reg.gauge("portfolio.best", v)
-    g = reg.snapshot()["gauges"]["portfolio.best"]
+        reg.gauge("queue_depth", v)
+    g = reg.snapshot()["gauges"]["queue_depth"]
     assert g == dict(last=5, min=1, max=5, count=5, mean=2.8)
 
 
