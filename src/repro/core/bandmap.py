@@ -271,9 +271,10 @@ def _map_dfg_portfolio(dfg: DFG, cgra: CGRAConfig, opts: "MapOptions",
                                       tracer=tracer)
             n_ops = len(sched.dfg.ops)
             # One unpacked-row cache per conflict graph, shared by the
-            # certificate search, the portfolio and the repair retries
-            # (memoized on the graph — harvest rounds and repair retries
-            # reuse it instead of re-unpacking n² rows each).
+            # certificate search and the portfolio (memoized on the
+            # graph, so harvest rounds reuse it instead of re-unpacking
+            # n² rows each).  Repair tries read the graph's neighbour
+            # masks, also memoized on it.
             shared_u8 = cg.row_cache(cache_limit)
             if ct.enabled:
                 cert, csp_sols = certify_ii_infeasible(
@@ -347,10 +348,6 @@ def _map_dfg_portfolio(dfg: DFG, cgra: CGRAConfig, opts: "MapOptions",
                                          row_cache_limit=cache_limit,
                                          op_of=op_of,
                                          group_move=pf.group_move)
-            # Repair retries reuse the same cache; when the graph was too
-            # big for it, row_cache() materialises one lazily so the
-            # retries don't each re-unpack n² rows.
-            row_cache = shared_u8
             seen_sols: set[bytes] = set()
             remaining = pf.iters
             # Harvest rounds: run the portfolio until some seed covers all
@@ -395,15 +392,11 @@ def _map_dfg_portfolio(dfg: DFG, cgra: CGRAConfig, opts: "MapOptions",
                         rs = base + rnd * 97 + int(k)
                         with trc.span("repair", ii=cur_ii,
                                       shortfall=n_ops - size):
-                            if row_cache is None:
-                                # Lazy n² unpack — on 16x16 graphs this
-                                # dominates the first repair's wall.
-                                row_cache = sbts.row_cache()
                             for rk in range(6):
                                 fixed = ejection_repair(
                                     cg.bits, sol, cg.op_vertices, op_of,
                                     depth=4, seed=rs * 13 + rk,
-                                    row_cache=row_cache)
+                                    masks=cg.nbr_masks(), tracer=tracer)
                                 trc.count("repair.tries")
                                 if int(fixed.sum()) >= n_ops:
                                     trc.count("repair.fixed")

@@ -171,6 +171,16 @@ class BitsetGraph:
         """Batched :meth:`row_u8` — one unpackbits call for many rows."""
         return unpack(self.rows[np.asarray(vs, dtype=np.int64)], self.n)
 
+    def row_masks(self) -> list[int]:
+        """Every row as one Python int (bit j = edge to vertex j), read
+        straight from the packed words: no n² unpack.  Set algebra on
+        these is a few machine words per op for the graph sizes the
+        repair search walks node by node."""
+        nb = self.words * 8
+        raw = self.rows.astype("<u8", copy=False).tobytes()
+        return [int.from_bytes(raw[i:i + nb], "little")
+                for i in range(0, self.n * nb, nb)]
+
     def neighbors(self, v: int) -> np.ndarray:
         return np.flatnonzero(self.row_u8(v))
 

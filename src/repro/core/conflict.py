@@ -81,6 +81,7 @@ class ConflictGraph:
     _adj: np.ndarray | None = dataclasses.field(default=None, repr=False)
     _u8_cache: np.ndarray | None = dataclasses.field(default=None,
                                                      repr=False)
+    _masks: list[int] | None = dataclasses.field(default=None, repr=False)
 
     @property
     def n(self) -> int:
@@ -119,6 +120,14 @@ class ConflictGraph:
                 return None
             self._u8_cache = self.bits.rows_u8(np.arange(self.n))
         return self._u8_cache
+
+    def nbr_masks(self) -> list[int]:
+        """Memoized neighbour masks, one Python int per vertex
+        (`BitsetGraph.row_masks`), shared by every repair try over this
+        graph: n² bits in all, an eighth of the unpacked row cache."""
+        if self._masks is None:
+            self._masks = self.bits.row_masks()
+        return self._masks
 
 
 def _occupancy(v: Vertex, ii: int) -> list[tuple]:

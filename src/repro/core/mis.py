@@ -29,6 +29,8 @@ import math as _math
 
 import numpy as np
 
+from repro.obs.trace import live
+
 from .bitset import BitsetGraph, as_bitset_graph, pack_bool
 
 # Unpacked-row caches ([n, n] uint8) are materialised only below this
@@ -165,7 +167,6 @@ class PortfolioSBTS:
                                      dtype=np.float32)
         self._pool_uses = 0
         self._stride = 0   # drawn (coprime to n) at the first _draw
-        self._u8_ext: np.ndarray | None = None  # row_cache() overflow copy
         # Group-move neighbourhood (off by default).  Everything below is
         # inert when disabled: the main loop's state arrays, RNG stream
         # and move sequence are untouched, so flag-off trajectories stay
@@ -189,18 +190,6 @@ class PortfolioSBTS:
         self._gm_rng = np.random.default_rng(
             (seed * 2654435761 + 0x9E3779B9) & 0x7FFFFFFFFFFFFFFF)
 
-    def row_cache(self) -> np.ndarray:
-        """Unpacked 0/1 adjacency ``uint8 [n, n]``, shared with callers
-        (e.g. ejection-repair retries).  When the constructor skipped the
-        cache (graph beyond the 32 MiB bound), materialise it lazily here
-        so the solver's per-move path keeps its per-move unpack policy
-        while one-shot consumers still get a single unpack."""
-        if self._u8 is not None:
-            return self._u8
-        if self._u8_ext is None:
-            self._u8_ext = self.g.rows_u8(np.arange(self.g.n))
-        return self._u8_ext
-
     def _rows(self, vs: np.ndarray) -> np.ndarray:
         return self._u8[vs] if self._u8 is not None else self.g.rows_u8(vs)
 
@@ -223,7 +212,6 @@ class PortfolioSBTS:
         # Per-super-iteration counter handle; the NullCounter default
         # keeps the untraced loop at one no-op call per [K, n] sweep and
         # never touches the RNG streams either way.
-        from repro.obs.trace import live
         iters_counter = live(tracer).counter("portfolio.iters")
         kick_counter = live(tracer).counter("portfolio.kicks")
         if self.g.n == 0 or self.k == 0:
@@ -628,72 +616,77 @@ def mis_indices(membership: np.ndarray) -> np.ndarray:
 def ejection_repair(adj, in_s: np.ndarray,
                     op_vertices: dict[int, list[int]],
                     op_of: np.ndarray, *, depth: int = 3,
-                    seed: int = 0,
-                    row_cache: np.ndarray | None = None) -> np.ndarray:
+                    seed: int = 0, masks: list[int] | None = None,
+                    tracer=None) -> np.ndarray:
     """Ejection-chain repair: try to place every op that has no selected
     candidate by inserting one of its candidates, evicting the (≤2)
     conflicting members, and recursively re-placing the evicted ops'
     alternatives up to ``depth``.  Closes the 1–2-vertex shortfalls SBTS
     plateaus on for tightly-packed instances (e.g. BusMap C4K8).
 
-    ``row_cache`` may supply the unpacked 0/1 adjacency (e.g. a
-    PortfolioSBTS's cache) so repeated repair attempts on one graph
-    don't each re-unpack it."""
+    The selection S and the banned set B are Python ints used as
+    bitmasks, so a search node's snapshot is the pair (S, B) and a
+    candidate's eviction count is ``(masks[v] & S).bit_count()``.
+    ``masks`` may supply the neighbour masks (`ConflictGraph.nbr_masks`)
+    so repeated tries on one graph build them once.  The search visits
+    at most 20,000 nodes; their number is counted as ``repair.nodes``
+    through ``tracer``.  Returns the repaired membership, ``bool [n]``."""
     g = as_bitset_graph(adj)
+    nbr = g.row_masks() if masks is None else masks
     rng = np.random.default_rng(seed)
-    in_s = in_s.copy()
-    conf = g.conflict_counts(pack_bool(in_s))
-    # Unpacked row cache: the chain search touches rows many times per
-    # node, so pay one unpackbits for the whole graph up front.
-    u8 = row_cache if row_cache is not None else (
-        g.rows_u8(np.arange(g.n)) if g.n
-        else np.zeros((0, 0), dtype=np.uint8))
-    doms = {op: np.asarray(ids, dtype=np.int64)
-            for op, ids in op_vertices.items()}
-    banned = np.zeros(g.n, dtype=bool)
-    nodes = [0]  # search-node budget (keeps worst-case bounded)
+    s = int.from_bytes(np.packbits(np.asarray(in_s, dtype=bool),
+                                   bitorder="little").tobytes(), "little")
+    banned = 0
+    doms: dict[int, list[int]] = {}
+    nodes = 0
+    # The tie-break doubles, drawn in blocks: consecutive `random` calls
+    # continue one stream, so a block sliced in order gives the values
+    # one call per node would.
+    draws: list[float] = []
+    pos = 0
 
     def place(op: int, d: int) -> bool:
-        nonlocal conf
-        nodes[0] += 1
-        if nodes[0] > 20000:
+        nonlocal s, banned, nodes, draws, pos
+        nodes += 1
+        if nodes > 20000:
             return False
-        # Batched candidate scoring over the row cache: one gather gives
-        # every alive candidate's current conflict count; a random key
-        # added before the stable argsort is the vectorised equivalent of
-        # shuffle-then-sort (fewest evictions first, random tie-break).
-        dom = doms[op]
-        alive = dom[~(in_s[dom] | banned[dom])]
-        if alive.size == 0:
+        dom = doms.get(op)
+        if dom is None:
+            dom = doms[op] = [int(v) for v in op_vertices[op]]
+        taken = s | banned
+        alive = [v for v in dom if not taken >> v & 1]
+        if not alive:
             return False
-        order = np.argsort(conf[alive] + rng.random(alive.size),
-                           kind="stable")
-        cands = alive[order]
-        n_evict = conf[cands]
-        for v, ne in zip(cands, n_evict):
+        # Fewest evictions first, random tie-break: a random key added
+        # to the count, then a stable sort.
+        k = len(alive)
+        if pos + k > len(draws):
+            draws = draws[pos:] + rng.random(max(k, 1024)).tolist()
+            pos = 0
+        n_evict = [(nbr[v] & s).bit_count() for v in alive]
+        keys = [ne + r for ne, r in zip(n_evict, draws[pos:pos + k])]
+        pos += k
+        for i in sorted(range(k), key=keys.__getitem__):
+            v, ne = alive[i], n_evict[i]
             if ne == 0:
-                in_s[v] = True
-                conf += u8[v]
+                s |= 1 << v
                 return True
             if d == 0 or ne > 2:
                 continue
-            evict = np.flatnonzero(u8[v] & in_s)
-            evicted_ops = [int(op_of[u]) for u in evict]
-            # Snapshot: recursive placements mutate state and `all` short-
-            # circuits, so restore wholesale on failure.
-            in_s_snap, conf_snap = in_s.copy(), conf.copy()
-            for u in evict:
-                in_s[u] = False
-                conf -= u8[u]
-            in_s[v] = True
-            conf += u8[v]
-            banned[v] = True
+            snap = s, banned
+            evict = nbr[v] & s
+            s ^= evict
+            s |= 1 << v
+            banned |= 1 << v
+            evicted_ops = []
+            while evict:                    # ascending vertex order
+                low = evict & -evict
+                evicted_ops.append(int(op_of[low.bit_length() - 1]))
+                evict ^= low
             if all(place(eo, d - 1) for eo in evicted_ops):
-                banned[v] = False
+                banned = snap[1]
                 return True
-            banned[v] = False
-            in_s[:] = in_s_snap
-            conf = conf_snap
+            s, banned = snap
         return False
 
     placed_ops = {int(op_of[v]) for v in np.flatnonzero(in_s)}
@@ -701,5 +694,9 @@ def ejection_repair(adj, in_s: np.ndarray,
         if op not in placed_ops:
             if place(op, depth):
                 placed_ops.add(op)
-    assert not g.any_conflict(pack_bool(in_s)), "repair broke independence"
-    return in_s
+    live(tracer).count("repair.nodes", nodes)
+    out = np.unpackbits(np.frombuffer(s.to_bytes((g.n + 7) // 8, "little"),
+                                      dtype=np.uint8),
+                        bitorder="little", count=g.n).astype(bool)
+    assert not g.any_conflict(pack_bool(out)), "repair broke independence"
+    return out

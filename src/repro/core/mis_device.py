@@ -250,11 +250,6 @@ class DeviceSBTS:
         """Per-seed best memberships ``bool [K, n]``."""
         return self._best[:, :self.g.n]
 
-    def row_cache(self) -> np.ndarray:
-        """Unpacked 0/1 adjacency for host-side repair consumers —
-        same contract as `PortfolioSBTS.row_cache`."""
-        return self.g.rows_u8(np.arange(self.g.n))
-
     # ----------------------------------------------------------- run
     def run(self, max_iters: int, target: int | None = None,
             cancel=None, tracer=None) -> np.ndarray:

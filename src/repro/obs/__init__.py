@@ -74,7 +74,7 @@ Counters (deterministic; ``check_regression.py`` gates
 ``certify.csp_nodes`` and ``portfolio.iters``):
 ``portfolio.iters``, ``portfolio.kicks``, ``certify.csp_nodes``,
 ``certify.orbit_skips``, ``exact.validations``,
-``comap.arbitration_retries``, and four counted in `map_dfg`'s harvest
+``comap.arbitration_retries``, and five counted in `map_dfg`'s harvest
 loop:
 
 =====================  ================================================
@@ -83,6 +83,8 @@ counter                one per / counted inside
 ``repair.tries``       `mis.ejection_repair` call / ``repair``
 ``repair.fixed``       such call whose result covers every op /
                        ``repair``
+``repair.nodes``       search node of such a call, counted once per
+                       call by `ejection_repair` / ``repair``
 ``validate.calls``     `validate_mapping` of a complete candidate, both
                        sources (``csp``, ``portfolio``) / ``validate``
 ``validate.rejects``   candidate the validator rejected / ``validate``

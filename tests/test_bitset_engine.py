@@ -214,10 +214,8 @@ def test_row_cache_limit_threads_through_map_dfg():
 @pytest.mark.slow
 def test_row_cache_fallback_hit_at_16x16_scale():
     """|V_C| ~ 10^4 (a 40-op generated kernel on a 16x16 PEA) exceeds
-    the default 32 MiB bound: the constructor must skip the cache, the
-    per-move fallback must still solve, and `row_cache()` must
-    materialise the full unpacked adjacency lazily for one-shot
-    consumers."""
+    the default 32 MiB bound: the constructor must skip the cache and
+    the per-move fallback must still solve."""
     from repro.core import scale_16x16_loop
     from repro.core.mis import ROW_CACHE_LIMIT
     big = CGRAConfig(rows=16, cols=16)
@@ -230,7 +228,3 @@ def test_row_cache_fallback_hit_at_16x16_scale():
     bests = sbts.run(150, target=len(sched.dfg.ops))
     for row in bests:                            # independence held
         assert not cg.bits.any_conflict(pack_bool(row))
-    rc = sbts.row_cache()
-    assert rc.shape == (cg.n, cg.n)
-    v = int(np.flatnonzero(bests[0])[0])
-    assert (rc[v] == cg.bits.row_u8(v)).all()

@@ -245,13 +245,15 @@ def test_counts_round_trip_through_json_and_chrome():
     with tr.span("repair", shortfall=2):
         tr.count("repair.tries", 6)
         tr.count("repair.fixed")
+        tr.count("repair.nodes", 540)
     with tr.span("validate"):
         tr.count("validate.calls")
     payload = json.loads(json.dumps(to_json(tr)))
     spans = from_json(payload)
     assert spans == tr.finished
     assert [s.counts for s in spans] == [
-        {"repair.tries": 6, "repair.fixed": 1}, {"validate.calls": 1}]
+        {"repair.tries": 6, "repair.fixed": 1, "repair.nodes": 540},
+        {"validate.calls": 1}]
     # A payload written before spans carried counts still loads.
     for sp in payload["spans"]:
         del sp["counts"]
@@ -259,23 +261,30 @@ def test_counts_round_trip_through_json_and_chrome():
     doc = json.loads(json.dumps(to_chrome_trace(tr)))
     xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
     assert xs[0]["args"] == {"shortfall": 2, "counts": {
-        "repair.tries": 6, "repair.fixed": 1}}
+        "repair.tries": 6, "repair.fixed": 1, "repair.nodes": 540}}
     assert xs[1]["args"] == {"counts": {"validate.calls": 1}}
 
 
 def test_repair_and_validate_counts_on_the_device_engine(monkeypatch):
     """A traced device-engine map (interpret mode on the CPU) of a loop
     kernel that repairs: every `ejection_repair` call is one
-    ``repair.tries`` on its ``repair`` span, every ``validate`` span one
-    ``validate.calls``, and exactly one validated candidate is kept."""
+    ``repair.tries`` on its ``repair`` span, and adds its search nodes
+    (as the frozen reference search counts them) to ``repair.nodes``
+    there; every ``validate`` span is one ``validate.calls``, and
+    exactly one validated candidate is kept."""
+    from _ejection_repair_ref import ejection_repair_ref
     from repro.core import bandmap
     from repro.core.workloads import make_loop_kernel
-    calls = []
+    calls, nodes = [], []
     real = bandmap.ejection_repair
 
     def counted(*args, **kwargs):
         out = real(*args, **kwargs)
+        want, n = ejection_repair_ref(*args, depth=kwargs["depth"],
+                                      seed=kwargs["seed"])
+        assert np.array_equal(out, want)
         calls.append(int(out.sum()))
+        nodes.append(n)
         return out
 
     monkeypatch.setattr(bandmap, "ejection_repair", counted)
@@ -286,9 +295,10 @@ def test_repair_and_validate_counts_on_the_device_engine(monkeypatch):
     assert res.ok
     recs = tr.finished
     counts = {n: tr.counter_value(n) for n in (
-        "repair.tries", "repair.fixed", "validate.calls",
+        "repair.tries", "repair.fixed", "repair.nodes", "validate.calls",
         "validate.rejects")}
     assert calls and counts["repair.tries"] == len(calls)
+    assert counts["repair.nodes"] == sum(nodes) >= len(calls)
     assert counts["repair.fixed"] == sum(c >= res.n_ops for c in calls)
     validates = [r for r in recs if r.name == "validate"]
     assert counts["validate.calls"] == len(validates)
@@ -298,7 +308,8 @@ def test_repair_and_validate_counts_on_the_device_engine(monkeypatch):
     for r in recs:
         for k, v in r.counts.items():
             where.setdefault(k, set()).add(r.name)
-    assert where["repair.tries"] == where["repair.fixed"] == {"repair"}
+    assert where["repair.tries"] == where["repair.fixed"] == \
+        where["repair.nodes"] == {"repair"}
     assert where["validate.calls"] == where["validate.rejects"] == \
         {"validate"}
     assert sum(r.counts.get("repair.tries", 0) for r in recs) == \
