@@ -48,6 +48,14 @@ mode, use_grf) at the given ``max_bus_fanout``) — exactly the family
 differentially confirm these verdicts (tests/test_analysis_demand.py).
 A bound never flags a combination any engine backend can map; it is a
 *lower* bound, free to be loose (the engine may fail even above it).
+
+The row bound mirrors the scheduler's operand staggering: VIOs tied to
+one row through shared consumers are the groups `schedule_dfg` gives
+distinct delivery slots, so below the component floor the scheduler
+emits no schedule, and at or above it it spreads each group over
+distinct slots where the ports allow (tests/test_schedule_stagger.py).
+The bound never depended on delivery times: it counts the slots one
+row port must serve.
 """
 
 from __future__ import annotations

@@ -10,10 +10,10 @@ Two severities:
   turns these into "cannot map at all" verdicts.
 - ``warn`` — the shape breaks the generator-family invariants that
   `core.workloads` upholds (and now asserts, sharing these exact
-  rules): such DFGs are mappable in principle but are the slow/doomed
-  corner cases — e.g. an op with two VIO predecessors needs both port
-  rows at once, and two VOOs sharing a producer contest one column —
-  the quantitative side of which `analysis.demand` bounds soundly.
+  rules): such DFGs are mappable but cost II — e.g. an op with two VIO
+  predecessors needs two delivery slots on one row port, and two VOOs
+  sharing a producer contest one column — the quantitative side of
+  which `analysis.demand` bounds soundly.
 
 Rules (names are stable test/CLI identifiers):
 
@@ -60,8 +60,10 @@ def generator_invariant_findings(dfg: DFG) -> list[LintFinding]:
     full lint share.
 
     - **multi-vio-pred**: every op has <= 1 distinct VIO predecessor
-      (bus delivery pins a consumer to its VIO's row; two VIO preds
-      demand two rows at once).
+      (bus delivery pins a consumer to its VIO's row, so two VIO preds
+      share one row port and need distinct delivery slots: the
+      scheduler staggers them, at the II floor `analysis.demand`
+      bounds).
     - **shared-voo-producer**: VOOs have exactly one producer and no
       two VOOs share one (two VOOs fed by one op land on one column
       and contest its OPORT/OBUS cells slot by slot).

@@ -263,7 +263,7 @@ def _map_dfg_portfolio(dfg: DFG, cgra: CGRAConfig, opts: "MapOptions",
                         dfg, cgra, mode=mode, ii=cur_ii,
                         max_ii=cur_ii, use_grf=sch.use_grf,
                         jitter=jitter, seed=seed,
-                        max_bus_fanout=sch.max_bus_fanout)
+                        max_bus_fanout=sch.max_bus_fanout, tracer=tracer)
             except RuntimeError:
                 continue
             cg = build_conflict_graph(sched, cgra,

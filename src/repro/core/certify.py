@@ -47,6 +47,11 @@ themselves sound for complete placements, see `conflict.py`).  It is NOT
 a proof that the II itself is infeasible for the kernel: a different
 schedule at the same II (other jitter, other routing split) may bind, and
 `map_dfg` accordingly skips only the certified (II, jitter) combination.
+A full-range negative (`MappingResult.proved_infeasible`) therefore
+holds relative to the schedule family `schedule_dfg` emits; that family
+gives the VIO operands of one op distinct delivery slots, since a
+family that delivered them in one slot would have every such kernel
+certified away (see `core/schedule.py`).
 The converse also does not hold: stage-3 *finding* a complete placement
 does not certify the II feasible — the validator may still reject it on
 the capacity structure a pairwise graph cannot express (flexible

@@ -16,6 +16,18 @@ a routing PE occupies one delivery slot, caches the datum, and re-drives a
 bus the next cycle, reaching (rows - 1) additional PEs in its column.  With
 a GRF, a datum parked in the GRF is readable by all PEs (capacity-limited),
 so GRF delivery removes the coverage constraint entirely.
+
+Operand staggering: a bus VIO reaches only the PEs of its port's row, so
+every consumer sits on that row, and a second VIO operand of one of them
+is pinned to the same row port (transitively over shared consumers).
+Two VIOs on one port cannot share a modulo slot (conflict rule 1), so
+the scheduler gives VIOs tied this way distinct delivery slots: a
+colouring of each tied group with II colours.  An operand delivered
+before its consumer's cycle - 1 is latched into the consumer PE's LRF
+and held until use (the validator counts the hold).  Where II is too
+small for the colouring, the (II, jitter) schedule fails as any other
+does.  A VIO that shares no consumer with another VIO is scheduled
+exactly as before.
 """
 
 from __future__ import annotations
@@ -39,6 +51,30 @@ class ScheduledDFG:
 
     def mslot(self, oid: int) -> int:
         return self.time[oid] % self.ii
+
+    def stagger_counts(self) -> tuple[int, int, int]:
+        """``(operands, staggered, hold_cycles)``: VIO->compute edges;
+        those into an op with two or more VIO operands that deliver
+        before the op's cycle - 1; and the cycles such operands wait
+        in the consumer's LRF."""
+        ops = self.dfg.ops
+        vio_in: dict[int, list] = {}
+        for e in self.dfg.edges:
+            if ops[e.src].kind == OpKind.VIN and \
+                    ops[e.dst].kind == OpKind.COMPUTE:
+                vio_in.setdefault(e.dst, []).append(e)
+        operands = staggered = hold = 0
+        for dst, edges in vio_in.items():
+            operands += len(edges)
+            if len({e.src for e in edges}) < 2:
+                continue
+            for e in edges:
+                wait = (self.time[dst] + e.distance * self.ii - 1
+                        - self.time[e.src])
+                if wait > 0:
+                    staggered += 1
+                    hold += wait
+        return operands, staggered, hold
 
     @property
     def n_routing_ops(self) -> int:
@@ -113,6 +149,7 @@ class _Scheduler:
         self.delivery: dict[int, str] = {}
         self.ports_alloc: dict[int, int] = {}
         self.heights = dfg.heights()
+        self.tie = _operand_ties(dfg)
         self.n_preds = {i: sum(1 for e in dfg.in_edges(i) if e.distance == 0)
                         for i in dfg.ops}
         self.ready: list[tuple[int, int]] = []
@@ -144,13 +181,30 @@ class _Scheduler:
                                    (-self.heights[e.dst], e.dst))
 
     # --------------------------------------------------------------- VIO
+    def _tied_slots(self, oid: int) -> set[int]:
+        """Modulo slots of the bus VIOs scheduled so far that are tied
+        to ``oid``'s row port (module docstring); copies of one datum
+        are never tied to each other."""
+        group = self.tie.get(oid)
+        if group is None:
+            return set()
+        ops = self.dfg.ops
+        datum = ops[oid].clone_of if ops[oid].clone_of >= 0 else oid
+        return {self.time[v] % self.ii for v in group
+                if v in self.time and self.delivery.get(v) == "bus"
+                and datum not in (v, ops[v].clone_of)}
+
+    def _parks_in_grf(self, rd: int) -> bool:
+        return (self.use_grf and rd > self.m_eff
+                and self.grf_live < self.cgra.grf)
+
     def _schedule_vio(self, oid: int, t: int) -> None:
         dfg, cgra, m = self.dfg, self.cgra, t % self.ii
         rd = dfg.rd(oid)
         m_bus = self.m_eff
         q_need = math.ceil(rd / m_bus)
 
-        if self.use_grf and rd > m_bus and self.grf_live < cgra.grf:
+        if self._parks_in_grf(rd):
             # Park the datum in the GRF: one port, coverage-unconstrained.
             self.iport[m] += 1
             self.grf_live += 1
@@ -178,6 +232,9 @@ class _Scheduler:
         for g in groups[1:]:
             cid = dfg.clone_vio(oid, g)
             clone_ids.append(cid)
+            if oid in self.tie:
+                self.tie[cid] = self.tie[oid]
+                self.tie[oid].append(cid)
             self.delivery[cid] = "bus"
             self.n_preds[cid] = 0
             self.heights[cid] = self.heights[oid]
@@ -254,8 +311,11 @@ class _Scheduler:
                 rd = self.dfg.rd(oid)
                 q_need = (1 if self.mode == "busmap"
                           else math.ceil(rd / self.m_eff))
+                taken = set() if self._parks_in_grf(rd) \
+                    else self._tied_slots(oid)
                 cands = [t for t in range(t0, t0 + ii)
-                         if self.iport[t % ii] < cgra.n_iports]
+                         if self.iport[t % ii] < cgra.n_iports
+                         and t % ii not in taken]
                 if cands:
                     full = [t for t in cands
                             if cgra.n_iports - self.iport[t % ii] >= q_need]
@@ -298,6 +358,21 @@ class _Scheduler:
             t_old = self.time[oid]
             if t_new <= t_old:
                 continue
+            taken = self._tied_slots(oid) \
+                if self.delivery.get(oid) == "bus" else set()
+            if taken:
+                # A tied operand moves to the latest slot before its
+                # first use that keeps its group's slots distinct.
+                for t in range(t_new, t_old, -1):
+                    m = t % ii
+                    if m not in taken and (m == t_old % ii or
+                                           self.iport[m]
+                                           < self.cgra.n_iports):
+                        self.iport[t_old % ii] -= 1
+                        self.iport[m] += 1
+                        self.time[oid] = t
+                        break
+                continue
             m_old, m_new = t_old % ii, t_new % ii
             if m_old == m_new:
                 self.time[oid] = t_new
@@ -308,12 +383,46 @@ class _Scheduler:
                 self.time[oid] = t_new
 
 
+def _operand_ties(dfg: DFG) -> dict[int, list[int]]:
+    """VIO -> the VIOs tied to its row port through ops that read two
+    or more VIOs (union-find, transitive), for VIOs with any tie; the
+    list is shared by the whole group."""
+    parent: dict[int, int] = {}
+
+    def find(x: int) -> int:
+        while parent.setdefault(x, x) != x:
+            parent[x] = parent[parent[x]]
+            x = parent[x]
+        return x
+
+    vio_preds: dict[int, set[int]] = {}
+    for e in dfg.edges:
+        if dfg.ops[e.src].kind == OpKind.VIN and \
+                dfg.ops[e.dst].kind in (OpKind.COMPUTE, OpKind.ROUTE):
+            vio_preds.setdefault(e.dst, set()).add(e.src)
+    for preds in vio_preds.values():
+        first, *rest = sorted(preds)
+        for v in rest:
+            parent[find(v)] = find(first)
+    groups: dict[int, list[int]] = {}
+    for v in parent:
+        groups.setdefault(find(v), []).append(v)
+    return {v: g for g in groups.values() if len(g) > 1 for v in g}
+
+
 def schedule_dfg(dfg: DFG, cgra: CGRAConfig, *, mode: str = "bandmap",
                  ii: int | None = None, max_ii: int = 64,
                  use_grf: bool | None = None, jitter: int = 0,
                  seed: int = 0,
-                 max_bus_fanout: int | None = None) -> ScheduledDFG:
-    """Iterative modulo scheduling.  Tries II = MII, MII+1, ... ≤ max_ii."""
+                 max_bus_fanout: int | None = None,
+                 tracer=None) -> ScheduledDFG:
+    """Iterative modulo scheduling.  Tries II = MII, MII+1, ... ≤ max_ii.
+
+    ``tracer`` (default None) counts, for the schedule emitted, its
+    VIO operands (``schedule.vio_operands``), the staggered ones
+    (``schedule.staggered``) and their LRF wait
+    (``schedule.hold_cycles``), as `ScheduledDFG.stagger_counts`
+    defines them, on the innermost open span."""
     assert mode in ("bandmap", "busmap")
     if use_grf is None:
         use_grf = cgra.grf > 0
@@ -325,5 +434,10 @@ def schedule_dfg(dfg: DFG, cgra: CGRAConfig, *, mode: str = "bandmap",
                          max_bus_fanout=max_bus_fanout).run()
         if out is not None:
             out.mii = the_mii
+            if tracer is not None:
+                operands, staggered, hold = out.stagger_counts()
+                tracer.count("schedule.vio_operands", operands)
+                tracer.count("schedule.staggered", staggered)
+                tracer.count("schedule.hold_cycles", hold)
             return out
     raise RuntimeError(f"no schedule found for II <= {max_ii}")

@@ -10,7 +10,10 @@ Checks, for a complete placement (one vertex per op):
    (VIO delivery on IBUS_r at its slot, VOO export on OBUS_c at its slot);
 3. LRF capacity: weight residency (one slot per MAC hosted by a PE) plus
    transient hold intervals (producer-hold, consumer-latch) fit `lrf` on
-   every (PE, slot), counting modulo-wraparound multiplicity;
+   every (PE, slot), counting modulo-wraparound multiplicity; a bus VIO
+   is latched by its consumer from delivery to use, so an operand the
+   scheduler staggered ahead of its op's cycle - 1 holds a register
+   for every cycle it waits;
 4. GRF capacity for GRF-parked data.
 """
 

@@ -34,7 +34,10 @@ the stochastic portfolio with the certificate machinery run to
   the portfolio searches the same family and therefore can never beat
   a proven exact II.  If the whole range up to ``max_ii`` is certified,
   the result is ``ok=False`` with ``proved_infeasible=True`` — the
-  certificate-backed negative the serve cache admits.
+  certificate-backed negative the serve cache admits.  The family
+  staggers the VIO operands of one op over distinct delivery slots
+  (`core/schedule.py`), so a kernel whose ops read two memory operands
+  is decided on schedules that can bind it.
 
 Budget knobs
 ------------

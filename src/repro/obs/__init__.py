@@ -74,21 +74,29 @@ Counters (deterministic; ``check_regression.py`` gates
 ``certify.csp_nodes`` and ``portfolio.iters``):
 ``portfolio.iters``, ``portfolio.kicks``, ``certify.csp_nodes``,
 ``certify.orbit_skips``, ``exact.validations``,
-``comap.arbitration_retries``, and five counted in `map_dfg`'s harvest
-loop:
+``comap.arbitration_retries``, three counted by `schedule_dfg` for
+each schedule it emits under `map_dfg`, and five counted in
+`map_dfg`'s harvest loop:
 
-=====================  ================================================
-counter                one per / counted inside
-=====================  ================================================
-``repair.tries``       `mis.ejection_repair` call / ``repair``
-``repair.fixed``       such call whose result covers every op /
-                       ``repair``
-``repair.nodes``       search node of such a call, counted once per
-                       call by `ejection_repair` / ``repair``
-``validate.calls``     `validate_mapping` of a complete candidate, both
-                       sources (``csp``, ``portfolio``) / ``validate``
-``validate.rejects``   candidate the validator rejected / ``validate``
-=====================  ================================================
+=========================  ================================================
+counter                    one per / counted inside
+=========================  ================================================
+``schedule.vio_operands``  VIO->compute edge of the schedule /
+                           ``schedule``
+``schedule.staggered``     such edge into an op with two or more VIO
+                           operands, delivered before the op's cycle - 1
+                           / ``schedule``
+``schedule.hold_cycles``   cycle such an operand waits in the op's
+                           LRF / ``schedule``
+``repair.tries``           `mis.ejection_repair` call / ``repair``
+``repair.fixed``           such call whose result covers every op /
+                           ``repair``
+``repair.nodes``           search node of such a call, counted once per
+                           call by `ejection_repair` / ``repair``
+``validate.calls``         `validate_mapping` of a complete candidate, both
+                           sources (``csp``, ``portfolio``) / ``validate``
+``validate.rejects``       candidate the validator rejected / ``validate``
+=========================  ================================================
 
 Counts sit on spans: an increment made through a live `Tracer` also
 lands in the ``counts`` of the innermost open span of the calling

@@ -162,16 +162,22 @@ def test_exact_confirms_structural_errors():
 
 # --------------------------------------- the shape hall.py misses
 def test_hall_alone_misses_dense_vio():
-    """At II=2 the dense-VIO scenario has no routing ops and no forced
-    drive pairs, so `hall_pressure_edges` adds zero edges — the tuple
-    demand bound is the only pre-mapping analysis that prunes it."""
+    """The dense-VIO scenario has no routing ops and no forced drive
+    pairs, so `hall_pressure_edges` adds zero edges to its graph — the
+    tuple demand bound is the only pre-mapping analysis that sees the
+    floor.  The scheduler gives the three tied VIOs distinct delivery
+    slots, so it emits no schedule below that floor (II 2), and at the
+    floor the graph hall leaves untouched binds."""
     d = dense_vio(3)
-    sched = schedule_dfg(d, CGRA, ii=2, max_ii=2)
+    assert demand_mii(d, CGRA) == 3       # the bound sees it
+    with pytest.raises(RuntimeError):
+        schedule_dfg(d, CGRA, ii=2, max_ii=2)
+    sched = schedule_dfg(d, CGRA, ii=3, max_ii=3)
     cg = build_conflict_graph(sched, CGRA, bus_pressure=True)
     n = hall_pressure_edges(cg.bits, cg.vertices, cg.op_vertices,
                             sched, CGRA)
     assert n == 0
-    assert demand_mii(d, CGRA) == 3       # ...but the bound sees it
+    assert map_dfg(d, CGRA, max_ii=3).ii == 3
 
 
 # ----------------------------------------- map_dfg static pre-pass
