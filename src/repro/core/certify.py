@@ -17,10 +17,12 @@ of increasing strength (and cost):
    so MIS < |ops| and the schedule is unbindable.  Vectorised over the
    ``uint64 [n, words]`` rows; milliseconds.
 3. **Bounded exhaustive search** — exact CSP over (op → candidate) with
-   most-constrained-op ordering and forward checking through the unpacked
-   row cache.  Exhausting the space *is* the certificate: no complete
-   independent placement exists.  The node budget keeps the worst case
-   bounded; past it the result is "unknown", never a false certificate.
+   most-constrained-op ordering and forward checking on Python-int
+   bitmasks (the alive candidates are one int; a move ANDs away the
+   chosen vertex's neighbour mask).  Exhausting the space *is* the
+   certificate: no complete independent placement exists.  The node
+   budget keeps the worst case bounded; past it the result is
+   "unknown", never a false certificate.
    The search runs in two phases: a cheap plain pass with a small node
    budget (feasible schedules usually resolve in tens of nodes), then —
    only on escalation — a symmetry-pruned pass that branches solely on
@@ -34,8 +36,9 @@ of increasing strength (and cost):
    non-symmetric search), so the pruning can never manufacture a false
    certificate.  It is what turns the BusMap II=MII exhaustions from
    ~10^5 nodes into a few hundred.  Graphs past the engine's
-   ROW_CACHE_LIMIT skip the unpacked cache (per-move row unpack, no
-   symmetry) rather than materialising n^2 bytes.
+   ROW_CACHE_LIMIT get no unpacked cache, so no symmetry pass, rather
+   than materialising n^2 bytes; their plain pass runs on the same
+   masks (n^2/8 bytes, the size of the packed rows).
 
 What a certificate proves — and what it does not
 ------------------------------------------------
@@ -254,71 +257,63 @@ def _search_complete(cg: ConflictGraph, node_budget: int,
     k = len(ops)
     if k == 0:
         return True, [np.zeros(0, dtype=bool)], 0
-    # Unpacked rows: share the caller's cache, or materialise one only
-    # within the engine's cache bound; past it fall back to per-move
-    # row unpack (O(n/8) per expansion, no n^2 allocation).  uint8 rows
-    # add directly into the int16 banned stack — no widened copy.
+    # Forward checking on Python-int bitmasks: the alive candidates are
+    # one int over the n vertices, and a move keeps ``alive & ~masks[v]``
+    # — a few machine words per node on graphs of a few hundred
+    # vertices.  The masks are the graph's memoized neighbour masks
+    # (n²/8 bytes, shared with repair).
+    masks = cg.nbr_masks() if isinstance(cg, ConflictGraph) \
+        else cg.bits.row_masks()
+    # The unpacked u8 rows serve only the symmetry verification of the
+    # second pass: share the caller's cache, or materialise one on
+    # demand within the engine's cache bound.  Past it there is no
+    # second pass (see below).
     cache_limit = ROW_CACHE_LIMIT if row_cache_limit is None \
         else row_cache_limit
-    if row_cache is not None:
-        u8 = row_cache
-    elif 0 < n * n <= cache_limit:
-        u8 = cg.bits.rows_u8(np.arange(n))
-    else:
-        u8 = None
+    has_u8 = row_cache is not None or 0 < n * n <= cache_limit
 
-    def row(v: int) -> np.ndarray:
-        return u8[v] if u8 is not None else cg.bits.row_u8(v)
-
-    op_code = np.empty(n, dtype=np.int64)
-    doms = []
-    offsets = np.empty(k, dtype=np.int64)
-    for i, o in enumerate(ops):
-        ids = np.asarray(cg.op_vertices[o], dtype=np.int64)
-        op_code[ids] = i
-        doms.append(ids)
-        offsets[i] = ids[0] if ids.size else 0
-    # build_conflict_graph lays candidates out op-contiguously, which
-    # turns the per-op alive counts into one reduceat; fall back to
-    # bincount for graphs assembled differently.
-    contiguous = (all(d.size and (np.diff(d) == 1).all() for d in doms)
-                  and (np.diff(offsets) > 0).all() and offsets[0] == 0
-                  and doms[-1][-1] == n - 1)
+    doms = [[int(v) for v in cg.op_vertices[o]] for o in ops]
+    dom_masks = [sum(1 << v for v in d) for d in doms]
     # MRV tie-break: among equally small domains, expand the op whose
     # candidates are the most constraining (highest mean degree) first —
     # its contradictions surface higher in the tree.  Empirically this
     # cuts the exhaustion on the tight BusMap II=MII instances by 1-2
     # orders of magnitude versus plain MRV.
-    tb = np.array([float(np.bitwise_count(cg.bits.rows[d]).sum())
-                   / max(d.size, 1) for d in doms])
-    tb = -0.9 * tb / (tb.max() + 1.0)
+    deg = [m.bit_count() for m in masks]
+    tb = [sum(deg[v] for v in d) / max(len(d), 1) for d in doms]
+    top = max(tb) + 1.0
+    tb = [-0.9 * t / top for t in tb]
     # Orbit-pruning hits, accumulated locally (one list append per skip
     # would be tracer traffic inside the node loop; one count at the
     # end is free) and published as the `certify.orbit_skips` counter.
-    orbit_skips = [0]
+    orbit_skips = 0
 
     def run(sym: tuple | None, budget: int,
-            ) -> tuple[bool | None, list[np.ndarray], int]:
-        unassigned = np.ones(k, dtype=bool)
-        chosen = np.full(k, -1, dtype=np.int64)
-        stack = np.zeros((k + 2, n), dtype=np.int16)
-        nodes = [0]
-        solutions: list[np.ndarray] = []
+            ) -> tuple[bool | None, list[list[int]], int]:
+        nonlocal orbit_skips
+        if sym is not None:
+            vrow, vcol, vdrv = sym
+        chosen = [-1] * k
+        nodes = 0
+        solutions: list[list[int]] = []
 
-        def dfs(depth: int, used_rows: frozenset,
-                used_cols: frozenset) -> bool | None:
-            nodes[0] += 1
-            if nodes[0] > budget:
+        def dfs(alive: int, rest: list[int], used_rows: int,
+                used_cols: int) -> bool | None:
+            # ``rest``: the unassigned ops, ascending; ``used_rows`` /
+            # ``used_cols``: bitmasks of the PEA rows/columns the
+            # partial assignment references.
+            nonlocal nodes, orbit_skips
+            nodes += 1
+            if nodes > budget:
                 return None
-            if cancel is not None and not nodes[0] & 63 \
-                    and cancel.is_set():
+            if cancel is not None and not nodes & 63 and cancel.is_set():
                 return None
-            if not unassigned.any():
+            if not rest:
                 if on_solution is not None:
                     # Online mode: accept (stop) or discard (keep
                     # exhausting) — see the docstring's UNSAT claim.
                     memb = np.zeros(n, dtype=bool)
-                    memb[chosen[chosen >= 0]] = True
+                    memb[chosen] = True
                     if on_solution(memb):
                         solutions.append(chosen.copy())
                         return True
@@ -327,24 +322,23 @@ def _search_complete(cg: ConflictGraph, node_budget: int,
                 # (returning False) until the requested count is in hand.
                 solutions.append(chosen.copy())
                 return len(solutions) >= n_solutions
-            banned = stack[depth]
-            alive = banned == 0
-            if contiguous:
-                counts = np.add.reduceat(alive,
-                                         offsets).astype(np.float64)
-            else:
-                counts = np.bincount(op_code[alive],
-                                     minlength=k).astype(np.float64)
-            counts += tb
-            counts[~unassigned] = np.inf
-            i = int(np.argmin(counts))
-            if counts[i] < 0.0:
+            # Most-constrained op: fewest alive candidates plus the
+            # tie-break; equal keys go to the lowest op index.
+            best, at = np.inf, 0
+            for p, j in enumerate(rest):
+                c = (alive & dom_masks[j]).bit_count() + tb[j]
+                if c < best:
+                    best, at = c, p
+            if best < 0.0:
                 return False
-            unassigned[i] = False
-            dom = doms[i]
+            i = rest[at]
+            child = rest[:at] + rest[at + 1:]
+            live_i = alive & dom_masks[i]
             seen: set = set()
             result: bool | None = False
-            for v in dom[alive[dom]]:
+            for v in doms[i]:
+                if not live_i >> v & 1:
+                    continue
                 nur, nuc = used_rows, used_cols
                 if sym is not None:
                     # Orbit representative: under the stabilizer of the
@@ -353,54 +347,52 @@ def _search_complete(cg: ConflictGraph, node_budget: int,
                     # interchangeable, and likewise columns — one
                     # candidate per (drive-kind, row-or-fresh,
                     # col-or-fresh) key suffices.
-                    vrow, vcol, vdrv = sym
-                    r_ref, c_ref = int(vrow[v]), int(vcol[v])
-                    key = (int(vdrv[v]),
-                           r_ref if r_ref < 0 or r_ref in used_rows
+                    r_ref, c_ref = vrow[v], vcol[v]
+                    key = (vdrv[v],
+                           r_ref if r_ref < 0 or used_rows >> r_ref & 1
                            else -2,
-                           c_ref if c_ref < 0 or c_ref in used_cols
+                           c_ref if c_ref < 0 or used_cols >> c_ref & 1
                            else -2)
                     if key in seen:
-                        orbit_skips[0] += 1
+                        orbit_skips += 1
                         continue
                     seen.add(key)
                     if r_ref >= 0:
-                        nur = used_rows | {r_ref}
+                        nur = used_rows | 1 << r_ref
                     if c_ref >= 0:
-                        nuc = used_cols | {c_ref}
+                        nuc = used_cols | 1 << c_ref
                 chosen[i] = v
-                np.add(banned, row(v), out=stack[depth + 1])
-                r = dfs(depth + 1, nur, nuc)
+                r = dfs(alive & ~masks[v], child, nur, nuc)
                 if r is None or r:
                     result = r
                     break
-            else:
-                chosen[i] = -1
-            unassigned[i] = True
             return result
 
-        verdict = dfs(0, frozenset(), frozenset())
-        return verdict, solutions, nodes[0]
+        verdict = dfs((1 << n) - 1, list(range(k)), 0, 0)
+        return verdict, solutions, nodes
 
     # Phase 1: plain search under a small budget — feasible schedules
     # usually resolve here, skipping the symmetry verification cost.
-    # Graphs past the row-cache bound stop here too: without the u8
-    # cache every node pays an O(n) row unpack and the symmetry
-    # verification (which needs the full cache) is unavailable, so a
-    # six-figure node budget burns seconds per (II, jitter) with no
-    # realistic chance of exhausting a |V_C| ~ 10^4 space — "unknown"
-    # after the cheap pass is the honest verdict at that scale.
+    # Graphs past the row-cache bound stop here too: the symmetry
+    # verification needs the full unpacked rows, and a six-figure node
+    # budget burns seconds per (II, jitter) with no realistic chance of
+    # exhausting a |V_C| ~ 10^4 space without it — "unknown" after the
+    # cheap pass is the honest verdict at that scale.
     budget1 = min(node_budget, _PLAIN_NODES_FIRST)
     verdict, sols, spent = run(None, budget1)
     if verdict is None and not sols and node_budget > budget1 \
-            and u8 is not None:
-        sym = _symmetry_attrs(cg, cgra, u8) if u8 is not None else None
+            and has_u8:
+        u8 = row_cache if row_cache is not None \
+            else cg.bits.rows_u8(np.arange(n))
+        sym = _symmetry_attrs(cg, cgra, u8)
+        if sym is not None:
+            sym = tuple(a.tolist() for a in sym)
         verdict, sols, spent2 = run(sym, node_budget - spent)
         spent += spent2
     placements = []
     for chosen in sols:
         p = np.zeros(n, dtype=bool)
-        p[chosen[chosen >= 0]] = True
+        p[chosen] = True
         placements.append(p)
     if placements:
         # An exhausted (False) or budget-out (None) sweep that still
@@ -408,7 +400,7 @@ def _search_complete(cg: ConflictGraph, node_budget: int,
         verdict = True
     trc = live(tracer)
     trc.count("certify.csp_nodes", spent)
-    trc.count("certify.orbit_skips", orbit_skips[0])
+    trc.count("certify.orbit_skips", orbit_skips)
     return verdict, placements, spent
 
 

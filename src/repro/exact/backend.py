@@ -15,15 +15,15 @@ the stochastic portfolio with the certificate machinery run to
   joint bus-demand bound (`repro.exact.hall`) folding per-(scope, bus,
   cycle) capacity into the graph.
 - **Search.**  `certify._search_complete` with its MRV /
-  most-constraining tie-break / forward checking and the *verified*
-  row/column symmetry-orbit pruning, run in online mode: every
-  complete conflict-free placement is handed to `validate_mapping`
-  (the engine's single soundness authority — concrete bus-instance
-  packing, LRF/GRF residency) as it is found.  Accept ⇒ SAT for this
-  schedule; exhaustion with every placement rejected ⇒ UNSAT for this
-  schedule (sound because the validator is equivariant under the
-  fabric's row/column relabelings, so rejecting an orbit
-  representative rejects its orbit — asserted in
+  most-constraining tie-break / forward checking on integer bitmasks
+  and the *verified* row/column symmetry-orbit pruning, run in online
+  mode: every complete conflict-free placement is handed to
+  `validate_mapping` (the engine's single soundness authority —
+  concrete bus-instance packing, LRF/GRF residency) as it is found.
+  Accept ⇒ SAT for this schedule; exhaustion with every placement
+  rejected ⇒ UNSAT for this schedule (sound because the validator is
+  equivariant under the fabric's row/column relabelings, so rejecting
+  an orbit representative rejects its orbit — asserted in
   tests/test_exact_differential.py).
 - **Verdicts.**  The first validator-accepted placement returns
   ``ok=True`` with ``optimal=True`` iff every lower (II, jitter)
