@@ -12,10 +12,10 @@ tests/test_analysis_astlint.py):
 
 ``mapping-result-ok``
     `MappingResult(ok=True, ...)` (or ``dataclasses.replace(...,
-    ok=True)``) may only be constructed in the validator-replayed
-    engine paths: ``core/bandmap.py`` and ``exact/backend.py``, where
-    every ``ok=True`` sits behind a ``report.ok`` check.  Anywhere
-    else it would mint an unvalidated positive.
+    ok=True)``) may only be constructed in ``core/bandmap.py``, whose
+    one result builder (`_result`, shared by both mapping backends)
+    takes ``ok`` from the validator's report.  Anywhere else it would
+    mint an unvalidated positive.
 
 ``cancel-poll``
     In the engine modules (``core/mis.py``, ``core/certify.py``,
@@ -90,7 +90,7 @@ import os
 import sys
 
 # --------------------------------------------------------------- config
-_OK_ALLOWED = ("repro/core/bandmap.py", "repro/exact/backend.py")
+_OK_ALLOWED = ("repro/core/bandmap.py",)
 _CANCEL_MODULES = ("repro/core/mis.py", "repro/core/certify.py",
                    "repro/core/bandmap.py", "repro/exact/backend.py",
                    "repro/exact/race.py")

@@ -1,11 +1,9 @@
 """The exact mapping backend: a complete prover over the engine's own
 search space.
 
-`exact_map_dfg` walks the same (II, jitter) schedule lattice as
-`bandmap.map_dfg` — the deterministic modulo scheduler at jitters
-0..3 per II, II escalating from max(MII, ``min_ii``) — but replaces
-the stochastic portfolio with the certificate machinery run to
-*decision*:
+`exact_map_dfg` runs inside the same (II, jitter) walk as
+`bandmap.map_dfg` (`bandmap._schedules`), but replaces the stochastic
+portfolio with the certificate machinery run to *decision*:
 
 - **Encoding.**  Per (II, jitter) schedule, the CP/SAT-style model is
   the mixed conflict graph itself: one variable per op over its
@@ -41,32 +39,28 @@ the stochastic portfolio with the certificate machinery run to
 
 Budget knobs
 ------------
-``node_budget`` caps CSP nodes per (II, jitter) combination (the knob
-`map_dfg(backend="exact")` maps ``certify_budget`` onto).  A
-combination that exhausts the budget is *unknown*: the backend keeps
-escalating II and can still return a mapping, but drops the
-``optimal`` / ``proved_infeasible`` claims — budgets degrade the
-claim, never the soundness.  ``cancel`` (`core.cancel.CancelToken`) is
-polled between combinations and every few dozen search nodes; a
-cancelled run returns a claim-less ``ok=False`` result, which is how
-the race driver (`repro.exact.race`) discards a losing prover
-mid-search.
+``certify.budget`` caps CSP nodes per (II, jitter) combination.  A
+combination that exhausts it is *unknown*: the backend keeps escalating
+II and can still return a mapping, but drops the ``optimal`` /
+``proved_infeasible`` claims — budgets degrade the claim, never the
+soundness.  ``cancel`` (`core.cancel.CancelToken`) is polled between
+combinations and every few dozen search nodes; a cancelled run returns
+a claim-less ``ok=False`` result, which is how the race
+(`repro.exact.race`) discards a losing prover mid-search.
 """
 
 from __future__ import annotations
 
-import time as _time
-
 import numpy as np
 
-from repro.core.bandmap import MappingResult
-from repro.core.certify import IICertificate, certify_ii_infeasible
+from repro.core.bandmap import (MappingResult, _Candidate, _result,
+                                _schedules, _Walk)
+from repro.core.certify import certify_ii_infeasible
 from repro.core.cgra import CGRAConfig
-from repro.core.conflict import build_conflict_graph
 from repro.core.dfg import DFG
-from repro.core.mis import ROW_CACHE_LIMIT, mis_indices
+from repro.core.mis import mis_indices
 from repro.core.options import MapOptions
-from repro.core.schedule import mii, schedule_dfg
+from repro.core.schedule import mii
 from repro.core.validate import validate_mapping
 from repro.obs.trace import live
 
@@ -99,97 +93,54 @@ def exact_map_dfg(dfg: DFG, cgra: CGRAConfig,
     """Prove the engine-optimal II (or certified infeasibility) for one
     DFG — see the module docstring for the exact claims.  Accepts the
     same `MapOptions` / dict / legacy-keyword forms as `map_dfg` so the
-    race driver can hand both backends the same problem; the CSP node
-    budget is ``certify.budget`` (the historical ``node_budget`` keyword
-    is still accepted as an alias) and ``certify.hall`` gates the joint
-    bus-demand bound (on by default — it only ever strengthens UNSAT
-    proofs)."""
+    race can hand both backends the same problem, plus the
+    historical ``node_budget`` alias of ``certify.budget``;
+    ``certify.hall`` gates the joint bus-demand bound (on by default —
+    it only ever strengthens UNSAT proofs)."""
     if "node_budget" in kwargs:
         kwargs = dict(kwargs)
         kwargs["certify_budget"] = kwargs.pop("node_budget")
     opts = MapOptions.coerce(options, kwargs)
-    mode, seed = opts.mode, opts.seed
-    sch, ct = opts.schedule, opts.certify
+    ct = opts.certify
     trc = live(tracer)
-    t_start = _time.perf_counter()
-    the_mii = mii(dfg, cgra)
-    cache_limit = ROW_CACHE_LIMIT if opts.portfolio.row_cache_limit \
-        is None else opts.portfolio.row_cache_limit
-    certificates: list[IICertificate] = []
+    walk = _Walk(opts, mii(dfg, cgra))
     proved_all = True      # every combination below the cursor decided
-    attempts = 0
-    last = (None, 0, (0, 0))
-    cancelled = False
-    for cur_ii in range(max(the_mii, sch.min_ii or 0), sch.max_ii + 1):
-        for jitter in (0, 1, 2, 3):
-            if cancel is not None and cancel.is_set():
-                cancelled = True
-                break
-            try:
-                sched = schedule_dfg(dfg, cgra, mode=mode, ii=cur_ii,
-                                     max_ii=cur_ii, use_grf=sch.use_grf,
-                                     jitter=jitter, seed=seed,
-                                     max_bus_fanout=sch.max_bus_fanout)
-            except RuntimeError:
-                # The deterministic scheduler produces nothing at this
-                # combination — there is no schedule to bind, so the
-                # combination is decided (vacuously UNSAT within the
-                # engine's family), not unknown.
-                continue
-            cg = build_conflict_graph(sched, cgra,
-                                      bus_pressure=opts.bus_pressure,
-                                      tracer=tracer)
-            if ct.hall:
-                hall_pressure_edges(cg.bits, cg.vertices,
-                                    cg.op_vertices, sched, cgra)
-            n_ops = len(sched.dfg.ops)
-            # Memoized on the graph; hall edges are already folded in,
-            # so the cache sees the strengthened adjacency.
-            shared_u8 = cg.row_cache(cache_limit)
-            sink = _ValidateSink(sched, cg, cgra)
-            with trc.span("exact-csp", ii=cur_ii, jitter=jitter,
-                          n_ops=n_ops) as xsp:
-                cert, _ = certify_ii_infeasible(
-                    cg, sched, cgra, jitter=jitter,
-                    node_budget=ct.budget, row_cache=shared_u8,
-                    row_cache_limit=cache_limit, on_solution=sink,
-                    cancel=cancel, tracer=tracer)
-                xsp.set(validations=sink.tried,
-                        verdict="sat" if sink.accepted is not None
-                        else "unsat" if cert is not None else "unknown")
-                if cert is not None:
-                    xsp.set(nodes=cert.nodes)
-            trc.count("exact.validations", sink.tried)
-            attempts += sink.tried
-            last = (sched, n_ops, (cg.n, cg.n_edges))
-            if sink.accepted is not None:
-                placement, report = sink.accepted
-                return MappingResult(
-                    ok=True, mode=mode, ii=cur_ii, mii=the_mii,
-                    n_routing_pes=sched.n_routing_ops,
-                    ports_per_vio=dict(sched.ports_allocated),
-                    placement=placement, sched=sched, report=report,
-                    cg_size=(cg.n, cg.n_edges), mis_size=n_ops,
-                    n_ops=n_ops, attempts=attempts,
-                    wall_s=_time.perf_counter() - t_start,
-                    certificates=certificates, optimal=proved_all,
-                    backend="exact")
+    last = _Candidate()
+    for cur_ii, jitter, sched, cg in _schedules(
+            dfg, cgra, walk, prepass=False, cancel=cancel, tracer=tracer):
+        if ct.hall:
+            hall_pressure_edges(cg.bits, cg.vertices,
+                                cg.op_vertices, sched, cgra)
+        # Memoized on the graph; hall edges are already folded in, so
+        # the cache sees the strengthened adjacency.
+        shared_u8 = cg.row_cache(walk.cache_limit)
+        n_ops = len(sched.dfg.ops)
+        sink = _ValidateSink(sched, cg, cgra)
+        with trc.span("exact-csp", ii=cur_ii, jitter=jitter,
+                      n_ops=n_ops) as xsp:
+            cert, _ = certify_ii_infeasible(
+                cg, sched, cgra, jitter=jitter,
+                node_budget=ct.budget, row_cache=shared_u8,
+                row_cache_limit=walk.cache_limit, on_solution=sink,
+                cancel=cancel, tracer=tracer)
+            xsp.set(validations=sink.tried,
+                    verdict="sat" if sink.accepted is not None
+                    else "unsat" if cert is not None else "unknown")
             if cert is not None:
-                certificates.append(cert)
-            else:
-                # Budget out (or cancelled mid-search): this
-                # combination is unknown, every claim past it degrades.
-                proved_all = False
-        if cancelled:
-            break
-    sched, n_ops, cg_size = last
-    return MappingResult(
-        ok=False, mode=mode, ii=sched.ii if sched else -1, mii=the_mii,
-        n_routing_pes=sched.n_routing_ops if sched else 0,
-        ports_per_vio=dict(sched.ports_allocated) if sched else {},
-        placement={}, sched=sched, report=None, cg_size=cg_size,
-        mis_size=0, n_ops=n_ops, attempts=attempts,
-        wall_s=_time.perf_counter() - t_start,
-        certificates=certificates,
-        proved_infeasible=proved_all and not cancelled,
-        backend="exact")
+                xsp.set(nodes=cert.nodes)
+        trc.count("exact.validations", sink.tried)
+        walk.attempts += sink.tried
+        if sink.accepted is not None:
+            placement, report = sink.accepted
+            return _result(walk, _Candidate(
+                sched, placement, report, n_ops, (cg.n, cg.n_edges)),
+                optimal=proved_all, backend="exact")
+        last = _Candidate(sched, cg_size=(cg.n, cg.n_edges))
+        if cert is not None:
+            walk.certificates.append(cert)
+        else:
+            # Budget out (or cancelled mid-search): this
+            # combination is unknown, every claim past it degrades.
+            proved_all = False
+    return _result(walk, last, backend="exact",
+                   proved_infeasible=proved_all and not walk.cancelled)

@@ -40,7 +40,9 @@ span name        emitted by / attributes
 ===============  =====================================================
 ``map-dfg``      `core.bandmap.map_dfg` root span — ``mode``, ``n_ops``
 ``static-prepass``  demand-bound II floor pass — ``floor``, ``skipped``
-``schedule``     per-(II, jitter) modulo schedule — ``ii``, ``jitter``
+``schedule``     per-(II, jitter) modulo schedule, opened by the walk
+                 both backends share (`bandmap._schedules`, so exact
+                 traces carry it too) — ``ii``, ``jitter``
 ``conflict-build``  `conflict.build_conflict_graph` — ``n_vertices``,
                  ``n_edges``; ``interpret`` (Pallas mode) when the
                  packed Pallas kernel built the graph
@@ -75,8 +77,8 @@ Counters (deterministic; ``check_regression.py`` gates
 ``portfolio.iters``, ``portfolio.kicks``, ``certify.csp_nodes``,
 ``certify.orbit_skips``, ``exact.validations``,
 ``comap.arbitration_retries``, three counted by `schedule_dfg` for
-each schedule it emits under `map_dfg`, and five counted in
-`map_dfg`'s harvest loop:
+each schedule it emits under either backend (portfolio or exact), and
+five counted by the portfolio backend's per-schedule search:
 
 =========================  ================================================
 counter                    one per / counted inside

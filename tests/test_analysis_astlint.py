@@ -20,6 +20,10 @@ VIOLATIONS = [
 def f(sched):
     return MappingResult(ok=True, mode="bandmap")
 """, "src/repro/serve/rogue.py"),
+    ("ok-in-exact-backend", "mapping-result-ok", """
+def f(sched):
+    return MappingResult(ok=True, mode="bandmap", backend="exact")
+""", "src/repro/exact/backend.py"),
     ("ok-replace", "mapping-result-ok", """
 import dataclasses
 def f(res):
