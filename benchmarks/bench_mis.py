@@ -364,7 +364,9 @@ def bench_group_move(quick: bool = False) -> list[dict]:
     portfolio stalls at ~90 % coverage, the group-move kick completes.
     Engine rows report coverage at iteration checkpoints under one
     budget; map rows run `map_dfg` end to end at pinned II=2 with the
-    flag off/on (certificates off so the portfolio does the work)."""
+    flag off/on (certificates and capacity edges off so the portfolio
+    does the work: the LRF-pressure edges alone let the flag-off
+    engine bind)."""
     from repro.core import GroupMoveConfig, make_tightly_coupled
     from repro.core.conflict import build_conflict_graph
     from repro.core.mis import PortfolioSBTS
@@ -398,9 +400,9 @@ def bench_group_move(quick: bool = False) -> list[dict]:
         print(f"group_move: {rows[-1]}")
     for mode, flag in (("map_off", False), ("map_on", True)):
         t0 = time.perf_counter()
-        r = map_dfg(dfg, big, certify=False, mis_restarts=4,
-                    mis_iters=2500, min_ii=2, max_ii=2, seed=0,
-                    group_move=flag)
+        r = map_dfg(dfg, big, certify=False, bus_pressure=False,
+                    mis_restarts=4, mis_iters=2500, min_ii=2, max_ii=2,
+                    seed=0, group_move=flag)
         rows.append(dict(
             kernel="tight8x8", mode=mode, ok=r.ok, ii=r.ii,
             coverage=f"{r.mis_size}/{r.n_ops}",

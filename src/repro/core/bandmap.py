@@ -147,17 +147,18 @@ def map_dfg(dfg: DFG, cgra: CGRAConfig,
     `MapOptions.from_kwargs`, whose `core.options.LEGACY_KNOBS` maps
     legacy names onto groups and drops unknown keys with a warning.
     Knob highlights: ``certify`` runs the certificate stages before the
-    portfolio; ``bus_pressure`` folds provable bus-capacity structure
-    into the conflict graph; ``static_prepass`` skips statically-doomed
-    IIs via the schedule-free demand analysis; ``min_ii`` floors the
-    walk (the co-mapper's common-II handle); ``row_cache_limit`` bounds
-    the unpacked-row caches in bytes; ``max_bus_fanout`` caps consumers
-    per delivery port; ``group_move`` enables the clustered kick
-    neighbourhood (`mis.GroupMoveConfig`); ``engine`` is ``"numpy"``
-    (`mis.PortfolioSBTS`, default) or ``"device"``
-    (`core.mis_device.DeviceSBTS`: ``device_seeds`` trajectories
-    through the `kernels.sbts_step` Pallas kernel, interpret mode on
-    CPU backends, rounds traced as "portfolio-device").
+    portfolio; ``bus_pressure`` folds provable capacity edges (bus
+    cells, LRF pairs) into the conflict graph; ``static_prepass`` skips
+    statically-doomed IIs via the schedule-free demand analysis;
+    ``min_ii`` floors the walk (the co-mapper's common-II handle);
+    ``row_cache_limit`` bounds the unpacked-row caches in bytes;
+    ``max_bus_fanout`` caps consumers per delivery port; ``group_move``
+    enables the clustered kick neighbourhood (`mis.GroupMoveConfig`);
+    ``engine`` is ``"numpy"`` (`mis.PortfolioSBTS`, default) or
+    ``"device"`` (`core.mis_device.DeviceSBTS`: ``device_seeds``
+    trajectories through the `kernels.sbts_step` Pallas kernel,
+    interpret mode on CPU backends, rounds traced as
+    "portfolio-device").
 
     ``cancel`` (`core.cancel.CancelToken`), ``tracer``
     (`repro.obs.Tracer`: a span tree rooted at "map-dfg", taxonomy in
@@ -371,8 +372,9 @@ def _harvest(sched: ScheduledDFG, cg: ConflictGraph, shared_u8,
              tracer=None, record=None) -> _Candidate | None:
     """Multi-seed SBTS portfolio on one schedule, in harvest rounds:
     validate each distinct complete solution (small shortfalls repaired
-    first); when the validator rejects all (bus congestion / LRF
-    overflow are invisible to the pairwise graph), re-arm or re-seed the
+    first); when the validator rejects all (bus congestion the graph's
+    capacity edges cannot prove, LRF overflow that takes three or more
+    ops or depends on drive timing), re-arm or re-seed the
     complete seeds until the iteration budget is spent.  Returns the
     first validated candidate, else the last tried (None if none)."""
     trc, rec = live(tracer), recording(record)

@@ -42,6 +42,12 @@ collapse to a single cell contested by another forced driver, the
 corresponding pair is infeasible in every complete placement and becomes a
 regular conflict edge — SBTS stops proposing placements `_assign_buses`
 is guaranteed to reject, without ever excluding a validatable placement.
+
+`lrf_pressure_edges` (same flag) does the same for LRF capacity: the
+validator's LRF charge that lands on an op's PE wherever it is placed
+(weight slot, operand latches, result holds) is schedule-fixed, so two
+ops whose charges together overflow the LRF can never share a PE, and
+their same-PE candidates become conflict edges.
 """
 
 from __future__ import annotations
@@ -176,9 +182,10 @@ def build_conflict_graph(sched: ScheduledDFG, cgra: CGRAConfig,
                          tracer=None) -> ConflictGraph:
     """Build the mixed conflict graph.  With ``bus_pressure=False``
     (default) the adjacency is byte-identical to the seed formulation
-    (`dense_conflicts_python` + `_dep_ok`); ``bus_pressure=True``
-    additionally folds the provable bus-capacity structure in via
-    :func:`bus_pressure_edges` (the pipeline default — see map_dfg).
+    (`dense_conflicts_python` + `_dep_ok`); ``bus_pressure=True`` (the
+    pipeline default — see map_dfg) additionally folds in provable
+    capacity edges (bus cells, LRF pairs): :func:`bus_pressure_edges`
+    and :func:`lrf_pressure_edges`.
 
     ``use_kernel`` selects the occupancy/clique formulation: False =
     packed bitset rows on the host (default), True = the dense-bool
@@ -191,11 +198,16 @@ def build_conflict_graph(sched: ScheduledDFG, cgra: CGRAConfig,
     ``interpret`` attr.
 
     ``tracer`` (default None) records the build as a "conflict-build"
-    span; the edge popcount for the span attrs is only paid on a live
-    tracer."""
+    span, counting the LRF-pressure vertex pairs as
+    ``conflict.lrf_edges`` where there are any; the edge popcount for
+    the span attrs is only paid on a live tracer."""
     from repro.obs.trace import live
-    with live(tracer).span("conflict-build", ii=sched.ii) as sp:
-        cg = _build_conflict_graph(sched, cgra, use_kernel, bus_pressure)
+    trc = live(tracer)
+    with trc.span("conflict-build", ii=sched.ii) as sp:
+        cg, n_lrf = _build_conflict_graph(sched, cgra, use_kernel,
+                                          bus_pressure)
+        if n_lrf:
+            trc.count("conflict.lrf_edges", n_lrf)
         if tracer is not None:
             sp.set(n_vertices=cg.n,
                    n_edges=int(np.bitwise_count(cg.bits.rows).sum()) // 2)
@@ -207,7 +219,8 @@ def build_conflict_graph(sched: ScheduledDFG, cgra: CGRAConfig,
 
 def _build_conflict_graph(sched: ScheduledDFG, cgra: CGRAConfig,
                           use_kernel: bool | str = False,
-                          bus_pressure: bool = False) -> ConflictGraph:
+                          bus_pressure: bool = False
+                          ) -> tuple[ConflictGraph, int]:
     dfg, ii = sched.dfg, sched.ii
     vertices: list[Vertex] = []
     op_vertices: dict[int, list[int]] = {}
@@ -271,10 +284,12 @@ def _build_conflict_graph(sched: ScheduledDFG, cgra: CGRAConfig,
     # over the producer x consumer candidate block.
     _add_dep_conflicts(bits, vertices, op_vertices, dfg)
 
+    n_lrf = 0
     if bus_pressure:
         bus_pressure_edges(bits, vertices, op_vertices, sched, cgra)
+        n_lrf = lrf_pressure_edges(bits, vertices, op_vertices, sched, cgra)
 
-    return ConflictGraph(vertices, bits, op_vertices, len(dfg.ops))
+    return ConflictGraph(vertices, bits, op_vertices, len(dfg.ops)), n_lrf
 
 
 def _forced_drive_slots(sched, oid: int, m: int) -> list[int] | None:
@@ -415,6 +430,102 @@ def bus_pressure_edges(bits: BitsetGraph, vertices, op_vertices,
     if cliques:
         bits.clear_diagonal()
     return n_pairs
+
+
+def _hold(a: np.ndarray, b: np.ndarray, ii: int) -> np.ndarray:
+    """Registers per modulo slot, ``int64 [len(a), ii]``, held over the
+    cycles ``a..b`` inclusive of each interval, wraparound multiplicity
+    counted (`validate._interval_slots`, vectorised; empty where
+    ``b < a``)."""
+    s = np.arange(ii)
+    a, b = np.asarray(a)[:, None], np.asarray(b)[:, None]
+    return np.where(b >= a, (b - s) // ii - (a - 1 - s) // ii, 0)
+
+
+def lrf_pair_floor(sched: ScheduledDFG) -> tuple[list[int], np.ndarray]:
+    """``(quad ops, floor)``: ``floor[a, b]`` is the LRF charge per
+    modulo slot that `validate_mapping` (§3) makes on the PE of quad ops
+    ``a`` and ``b`` (indices into the list) in any placement that puts
+    both on that PE.
+
+    Each op's own part is what lands on its PE whatever that PE is: its
+    weight slot (compute ops), the latch of each bus-delivered VIO
+    operand from delivery to use, the hold of each VOO result from
+    ready to export, and, per quad->quad edge, the producer's ready
+    cycle and the consumer's use cycle (a self-edge holds the whole
+    interval).  Edges used before they are ready add nothing: the
+    validator rejects those placements anyway.  An edge between ``a``
+    and ``b`` is charged instead as the one same-PE interval ``[ready,
+    use]``, which is what the validator charges when they share a PE.
+    The diagonal is not a pair and means nothing."""
+    dfg, ii = sched.dfg, sched.ii
+    quad = [o for o, op in dfg.ops.items()
+            if op.kind in (OpKind.COMPUTE, OpKind.ROUTE)]
+    row = {o: k for k, o in enumerate(quad)}
+    held = []                    # (row, first, last): an op's own charge
+    shared = []                  # (row, row, ready, use): a->b, a != b
+    for o in quad:
+        if dfg.ops[o].kind == OpKind.COMPUTE:
+            held.append((row[o], 0, ii - 1))          # weight slot
+    for e in dfg.edges:
+        src, dst = dfg.ops[e.src], dfg.ops[e.dst]
+        t_src = sched.time[e.src]
+        t_dst = sched.time[e.dst] + e.distance * ii
+        if src.kind == OpKind.VIN:
+            if e.dst in row and sched.delivery.get(e.src, "bus") == "bus":
+                held.append((row[e.dst], t_src, t_dst))
+            continue
+        if e.src not in row:
+            continue
+        t_ready = t_src + src.latency
+        if dst.kind == OpKind.VOUT:
+            held.append((row[e.src], t_ready, t_dst))
+        elif e.dst in row and t_dst >= t_ready:
+            if e.src == e.dst:
+                held.append((row[e.src], t_ready, t_dst))
+            else:
+                held += [(row[e.src], t_ready, t_ready),
+                         (row[e.dst], t_dst, t_dst)]
+                shared.append((row[e.src], row[e.dst], t_ready, t_dst))
+    own = np.zeros((len(quad), ii), np.int64)
+    if held:
+        k, a, b = np.asarray(held).T
+        np.add.at(own, k, _hold(a, b, ii))
+    floor = own[:, None, :] + own[None, :, :]
+    if shared:
+        ka, kb, ready, use = np.asarray(shared).T
+        fix = (_hold(ready, use, ii) - _hold(ready, ready, ii)
+               - _hold(use, use, ii))
+        np.add.at(floor, (ka, kb), fix)
+        np.add.at(floor, (kb, ka), fix)
+    return quad, floor
+
+
+def lrf_pressure_edges(bits: BitsetGraph, vertices, op_vertices,
+                       sched: ScheduledDFG, cgra: CGRAConfig) -> int:
+    """Connect the same-PE candidates of two quad ops whose
+    `lrf_pair_floor` exceeds ``cgra.lrf`` in some modulo slot.  The
+    validator rejects every complete placement that puts such a pair on
+    one PE, so the edges are sound: no validator-accepted placement
+    holds one.
+
+    Returns the number of vertex pairs added (0 when no pair overflows,
+    and the graph is then unchanged)."""
+    quad, floor = lrf_pair_floor(sched)
+    ia, ib = np.nonzero(np.triu(floor.max(axis=2) > cgra.lrf, 1))
+    if not ia.size:
+        return 0
+    # A quad op's candidates come PE by PE (`_build_conflict_graph`):
+    # one per PE, two (row and column drive) for a routing op.  -1 pads.
+    cand = np.full((len(quad), cgra.n_pes, 2), -1, np.int64)
+    for k, o in enumerate(quad):
+        vs = np.asarray(op_vertices[o], np.int64).reshape(cgra.n_pes, -1)
+        cand[k, :, :vs.shape[1]] = vs
+    src, dst = np.broadcast_arrays(cand[ia][:, :, :, None],
+                                   cand[ib][:, :, None, :])
+    keep = (src >= 0) & (dst >= 0)
+    bits.add_edges(src[keep], dst[keep])
+    return int(keep.sum())
 
 
 def bitset_group_conflicts(vertices, op_vertices, ii: int) -> BitsetGraph:

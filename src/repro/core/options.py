@@ -126,6 +126,7 @@ class MapOptions:
     mode: str = "bandmap"
     seed: int = 0
     backend: str = "portfolio"
+    # Provable capacity edges (bus cells, LRF pairs) in the conflict graph.
     bus_pressure: bool = True
     schedule: ScheduleOptions = ScheduleOptions()
     certify: CertifyOptions = CertifyOptions()

@@ -9,9 +9,9 @@ portfolio with the certificate machinery run to *decision*:
   the mixed conflict graph itself: one variable per op over its
   candidate tuples (TIN port tuples, TOUT port tuples, QUAD PE slots,
   routing drives), pairwise constraints = occupancy cliques +
-  dependency realizability + `bus_pressure_edges` + the Hall-style
-  joint bus-demand bound (`repro.exact.hall`) folding per-(scope, bus,
-  cycle) capacity into the graph.
+  dependency realizability + `bus_pressure_edges` + `lrf_pressure_edges`
+  + the Hall-style joint bus-demand bound (`repro.exact.hall`) folding
+  per-(scope, bus, cycle) capacity into the graph.
 - **Search.**  `certify._search_complete` with its MRV /
   most-constraining tie-break / forward checking on integer bitmasks
   and the *verified* row/column symmetry-orbit pruning, run in online

@@ -39,7 +39,7 @@ only shrink under taking subsets/chosen candidates, so a conservative
 union can never manufacture a false conflict.  The bound is used by
 the exact backend (`repro.exact.backend`), where stronger pruning
 means smaller UNSAT exhaustions; the portfolio path keeps its
-byte-pinned `bus_pressure_edges`-only graph.
+byte-pinned graph of `bus_pressure_edges` and `lrf_pressure_edges`.
 """
 
 from __future__ import annotations

@@ -77,8 +77,9 @@ Counters (deterministic; ``check_regression.py`` gates
 ``portfolio.iters``, ``portfolio.kicks``, ``certify.csp_nodes``,
 ``certify.orbit_skips``, ``exact.validations``,
 ``comap.arbitration_retries``, three counted by `schedule_dfg` for
-each schedule it emits under either backend (portfolio or exact), and
-five counted by the portfolio backend's per-schedule search:
+each schedule it emits under either backend (portfolio or exact), one
+counted by `build_conflict_graph` where it is non-zero, and five
+counted by the portfolio backend's per-schedule search:
 
 =========================  ================================================
 counter                    one per / counted inside
@@ -90,6 +91,8 @@ counter                    one per / counted inside
                            / ``schedule``
 ``schedule.hold_cycles``   cycle such an operand waits in the op's
                            LRF / ``schedule``
+``conflict.lrf_edges``     vertex pair `conflict.lrf_pressure_edges`
+                           adds / ``conflict-build``
 ``repair.tries``           `mis.ejection_repair` call / ``repair``
 ``repair.fixed``           such call whose result covers every op /
                            ``repair``

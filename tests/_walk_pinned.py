@@ -11,9 +11,10 @@ default options:
   jitter, stage, nodes).
 
 Two more cases map ``loop4x4s124`` and ``loop4x4s128`` on the device
-engine (interpret mode on the CPU): the benchmark's engine, whose
-harvest re-seeds at most 16 complete seeds a round (``loop4x4s124``
-reaches that cap).  Refresh the pinned copy with
+engine (interpret mode on the CPU): the benchmark's engine.  Both bind
+in the first harvest round of their binding II, so neither reaches the
+harvest's cap of 16 re-seeded complete seeds a round.  Refresh the
+pinned copy with
 
     PYTHONPATH=src python tests/_walk_pinned.py
 

@@ -12,6 +12,7 @@ from _hypothesis_compat import given, settings, st
 from repro.core.bitset import BitsetGraph, pack_bool
 from repro.core.cgra import CGRAConfig
 from repro.core.conflict import build_conflict_graph
+from repro.core.kernels_polybench import build
 from repro.core.mis import ejection_repair, solve_mis
 from repro.core.schedule import schedule_dfg
 from repro.core.workloads import make_cnkm, make_loop_kernel
@@ -159,8 +160,9 @@ def test_nbr_masks_are_the_rows_and_built_once(monkeypatch):
 
 
 def test_masks_built_once_per_graph_in_a_map(monkeypatch):
-    """A map that repairs many times builds each conflict graph's masks
-    at most once."""
+    """A map that repairs many times (seidel-2d unrolled four times,
+    whose validator rejects no pairwise conflict edge rules out) builds
+    each conflict graph's masks at most once."""
     from repro.core import bandmap
     from repro.core.bandmap import map_dfg
     builds, tries = [], []
@@ -177,9 +179,7 @@ def test_masks_built_once_per_graph_in_a_map(monkeypatch):
 
     monkeypatch.setattr(BitsetGraph, "row_masks", counted)
     monkeypatch.setattr(bandmap, "ejection_repair", repair_seen)
-    dfg = make_loop_kernel(2, 4, 3, 2, n_carries=124 % 3, max_distance=2,
-                           seed=124)
-    res = map_dfg(dfg, CGRA)
+    res = map_dfg(build("seidel-2d", 4), CGRA)
     assert res.ok
     assert len(tries) > len(builds) > 0
     assert len({id(g) for g in builds}) == len(builds)

@@ -181,10 +181,13 @@ def test_tight_workload_engine_stalls_without_kick():
 def test_tight_workload_map_dfg_flag_on_vs_off():
     """End to end: at equal iteration budget and pinned II, `map_dfg`
     with group_move enabled produces a *valid* full-coverage binding
-    the flag-off engine does not reach (the acceptance scenario)."""
+    the flag-off engine does not reach (the acceptance scenario).  The
+    graph carries no capacity edges: with them the LRF-pressure edges
+    rule out the placements the flag-off engine is rejected on here,
+    and it binds too."""
     d = make_tightly_coupled(8, 8, 2, link_run=6, seed=0)
     kw = dict(certify=False, mis_restarts=4, mis_iters=2500,
-              min_ii=2, max_ii=2, seed=0)
+              min_ii=2, max_ii=2, seed=0, bus_pressure=False)
     r_off = map_dfg(d, BIG, **kw)
     r_on = map_dfg(d, BIG, group_move=True, **kw)
     assert not r_off.ok and r_off.mis_size < r_off.n_ops
